@@ -17,7 +17,8 @@ Two entry points, selected by argv[1]:
     bus bandwidth on the real 8-device host-CPU mesh.
 ``all`` (default) — both.
 
-Run with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+Run with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.  This
+is a CPU rehearsal: it forces 8 host devices and never runs on the chip.
 """
 
 import json
@@ -32,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.collectives.dispatch import DispatchConfig, reset_dispatcher
@@ -73,7 +73,7 @@ def legs():
         busbytes = 2 * 7 / 8 * (mib << 20)
 
         def spmd(fn):
-            return jax.jit(shard_map(fn, mesh=mesh, in_specs=P("x"),
+            return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("x"),
                                      out_specs=P("x")))
 
         t_def, cv_def = timeit(spmd(lambda v: lax.psum(v, "x")), x)
@@ -125,7 +125,7 @@ def closed_loop():
     disp.register_mesh_sync(bridge.flush)
 
     def spmd(fn):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=P("x"),
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("x"),
                                  out_specs=P("x")))
 
     for kib in CLOSED_SIZES_KIB:

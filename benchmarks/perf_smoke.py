@@ -118,11 +118,8 @@ def smoke() -> dict:
     # bridge win CI-visible: with clean host maps, repeat calls must
     # perform ZERO map uploads (asserted structurally via the bridge's
     # dirty counters — timing columns stay informational, so CI cannot
-    # flake on machine noise).  The uint64 tier needs a working x64
-    # scope; the 32-bit-pair tier runs everywhere.
-    from repro.compat import have_x64
-    pallas_tiers = ["pallas32"] + (["pallas"] if have_x64() else [])
-    for tier in pallas_tiers:
+    # flake on machine noise).
+    for tier in ("pallas32", "pallas"):
         rt_pal = PolicyRuntime(tier=tier)
         lp_pal = rt_pal.load(latency_argmin_tuner.program)
         _seed_loop(rt_pal)
@@ -279,6 +276,8 @@ def run(report) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     rec = smoke()
     print(json.dumps(rec, separators=(",", ":")))
     raise SystemExit(0 if rec["ok"] else 1)

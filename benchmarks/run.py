@@ -33,6 +33,12 @@ leg fails.
 
 Prints ``section,name,key=value,...`` CSV-ish lines and writes
 results/bench.json.
+
+Every suite here is a CPU rehearsal, and none is on the chip's path
+(``chip_smoke.py`` is): ``--ci`` and ``table2`` start child processes
+after this process may have touched JAX, which a chip held by the parent
+would refuse, and the table2 driver forces 8 host-CPU devices.  Their
+timings say nothing about a TPU.
 """
 
 from __future__ import annotations
@@ -213,6 +219,8 @@ def main() -> None:
     ap.add_argument("--ci", action="store_true",
                     help="run the CI guard (perf smoke + tier-1 pytest)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.ci:
         sys.exit(run_ci())
     picks = args.suites or list(SUITES)

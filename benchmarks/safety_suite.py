@@ -30,11 +30,8 @@ _MATRIX_POINTS = ("helper", "map_rmw", "decide", "bridge_upload",
 
 
 def _fault_tiers():
-    from repro.compat import have_x64
     from repro.core.cc import have_cc
-    tiers = ["interp", "jit", "jaxc", "pallas32"]
-    if have_x64():
-        tiers.insert(3, "pallas")
+    tiers = ["interp", "jit", "jaxc", "pallas", "pallas32"]
     if have_cc():
         tiers.append("native")
     return tiers

@@ -89,22 +89,22 @@ def _run_loop_section(report, ctx) -> None:
 
         jaxc_ok = None
         try:
-            from repro.compat import enable_x64, have_x64
+            import jax
+
             from repro.core.jaxc import (compile_jax, ctx_to_vec,
                                          map_to_array)
-            if have_x64():
-                rt = PolicyRuntime(use_interpreter=True)
-                rt.load(pol.program)
-                _seed_loop_maps(rt)
-                arrays = {d.name: map_to_array(rt.maps.get(d.name))
-                          for d in pol.program.maps}
-                fn, _ = compile_jax(pol.program)
-                with enable_x64(True):
-                    jret, vec_out, _ = fn(ctx_to_vec(bytearray(ctx.buf)),
-                                          arrays)
-                jaxc_ok = (int(jret) == tiers["interp"][1]
-                           and np.asarray(vec_out).astype("<u8")
-                           .tobytes() == bufs["interp"])
+            rt = PolicyRuntime(use_interpreter=True)
+            rt.load(pol.program)
+            _seed_loop_maps(rt)
+            arrays = {d.name: map_to_array(rt.maps.get(d.name))
+                      for d in pol.program.maps}
+            fn, _ = compile_jax(pol.program)
+            with jax.enable_x64(True):
+                jret, vec_out, _ = fn(ctx_to_vec(bytearray(ctx.buf)),
+                                      arrays)
+            jaxc_ok = (int(jret) == tiers["interp"][1]
+                       and np.asarray(vec_out).astype("<u8")
+                       .tobytes() == bufs["interp"])
         except Exception:
             jaxc_ok = False
 
@@ -197,7 +197,6 @@ def pallas_differential(report=None):
     ``benchmarks.run --ci``."""
     import jax
 
-    from repro.compat import enable_x64, have_x64
     from repro.core.jaxc import (JaxcError, check_supported, compile_jax,
                                  ctx_to_vec, map_to_array)
     from repro.core.pallasc import compile_pallas
@@ -207,9 +206,6 @@ def pallas_differential(report=None):
 
     rec = {"suite": "table1_pallas", "ok": True, "n_ineligible": 0,
            "ineligible": [], "policies": {}}
-    if not have_x64():
-        rec["skipped"] = "jax build lacks a working enable_x64"
-        return rec
     ctx = make_ctx("tuner", msg_size=8 * MiB, comm_id=0, n_ranks=8,
                    max_channels=32)
     table1 = [(p.program, seed_maps, ctx) for p in
@@ -251,7 +247,7 @@ def pallas_differential(report=None):
                     _t.append(1)
                     return _fn(vec, arrs)
                 jfn = jax.jit(traced)
-                with enable_x64(True):
+                with jax.enable_x64(True):
                     ret, vec_out, arrs_out = jfn(
                         ctx_to_vec(bytearray(ctx.buf)), arrays)
                     # second decision feeds the updated map state back in:
@@ -290,7 +286,6 @@ def pallas32_differential(report=None):
     but the pair leg still gates (that is its reason to exist)."""
     import jax
 
-    from repro.compat import enable_x64, have_x64
     from repro.core.jaxc import (JaxcError, check_supported, compile_jax,
                                  ctx_to_vec, map_to_array)
     from repro.core.lower32 import (ctx_to_vec32, map_to_array32,
@@ -369,33 +364,32 @@ def pallas32_differential(report=None):
         row["ok"] = row["ok"] and ok32
 
         # -- uint64 in-graph legs (need the x64 scope) -----------------
-        if have_x64():
-            for tier, compiler in (
-                    ("jaxc", compile_jax),
-                    ("pallas", lambda p: compile_pallas(p, word_width=64))):
-                fn, names = compiler(prog)
-                traces = []
+        for tier, compiler in (
+                ("jaxc", compile_jax),
+                ("pallas", lambda p: compile_pallas(p, word_width=64))):
+            fn, names = compiler(prog)
+            traces = []
 
-                def traced(vec, arrs, _fn=fn, _t=traces):
-                    _t.append(1)
-                    return _fn(vec, arrs)
-                jfn = jax.jit(traced)
-                with enable_x64(True):
-                    ret, vec_out, arrs_out = jfn(
-                        ctx_to_vec(bytearray(ctx.buf)),
-                        fresh_arrays(map_to_array))
-                    jfn(ctx_to_vec(bytearray(ctx.buf)),
-                        {n: arrs_out[n] for n in names})
-                    state = _decoded_device_state(prog, names, arrs_out,
-                                                  array_to_map)
-                tier_ok = (
-                    int(ret) == want_ret
-                    and np.asarray(vec_out).astype("<u8").tobytes()
-                    == want_buf
-                    and all(state[n] == want_state[n] for n in names)
-                    and len(traces) == 1)
-                row[tier + "_ok"] = tier_ok
-                row["ok"] = row["ok"] and tier_ok
+            def traced(vec, arrs, _fn=fn, _t=traces):
+                _t.append(1)
+                return _fn(vec, arrs)
+            jfn = jax.jit(traced)
+            with jax.enable_x64(True):
+                ret, vec_out, arrs_out = jfn(
+                    ctx_to_vec(bytearray(ctx.buf)),
+                    fresh_arrays(map_to_array))
+                jfn(ctx_to_vec(bytearray(ctx.buf)),
+                    {n: arrs_out[n] for n in names})
+                state = _decoded_device_state(prog, names, arrs_out,
+                                              array_to_map)
+            tier_ok = (
+                int(ret) == want_ret
+                and np.asarray(vec_out).astype("<u8").tobytes()
+                == want_buf
+                and all(state[n] == want_state[n] for n in names)
+                and len(traces) == 1)
+            row[tier + "_ok"] = tier_ok
+            row["ok"] = row["ok"] and tier_ok
         rec["policies"][prog.name] = row
         rec["ok"] = rec["ok"] and row["ok"]
         if report is not None:

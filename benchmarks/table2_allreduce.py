@@ -13,6 +13,11 @@ Two parts:
     deltas without claiming the paper's magnitudes.  A driver failure
     raises (the suite harness counts it) and surfaces the full stderr
     tail; the CI entry point is :func:`ci_closed_loop`.
+
+Part (b) is a CPU rehearsal and stays off the chip: it starts its driver
+as a child process after this process may have touched JAX (a chip
+belongs to one process), and the driver forces 8 host-CPU devices.  No
+number it prints is a device metric.
 """
 
 from __future__ import annotations

@@ -11,7 +11,6 @@ with map state threaded functionally (the beyond-paper tier).
 import jax
 import jax.numpy as jnp
 
-from repro.compat import enable_x64
 from repro.core import (PolicyRuntime, VerifierError, assemble, make_ctx,
                         map_decl, policy, verify)
 from repro.core.jaxc import compile_jax, ctx_to_vec, map_to_array
@@ -73,7 +72,7 @@ def main():
     @jax.jit
     def training_step_with_policy(map_state, msg_bytes):
         vec = ctx_to_vec(make_ctx("tuner").buf)
-        with enable_x64(True):
+        with jax.enable_x64(True):
             vec = vec.at[fields.index("msg_size")].set(
                 msg_bytes.astype(jnp.uint64))
         ret, vec_out, maps_out = fn(vec, {"hist": map_state})
@@ -83,8 +82,8 @@ def main():
     rt = PolicyRuntime()
     rt.load(prog)
     state = map_to_array(rt.maps.get("hist"))
-    # x64 scope wraps the jit calls (0.4.x boundary-canonicalization rule)
-    with enable_x64(True):
+    # x64 scope wraps the jit calls so uint64 inputs are not canonicalized
+    with jax.enable_x64(True):
         for mib in (0.5, 8, 64, 512):
             nch, state = training_step_with_policy(
                 state, jnp.uint32(int(mib * MiB) & 0xFFFFFFFF))

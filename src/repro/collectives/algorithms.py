@@ -37,8 +37,7 @@ from ..core.context import Proto
 
 
 def _axis_size(axis_name: str) -> int:
-    from ..compat import axis_size
-    return axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def wire_dtypes(protocol: int, dtype) -> Tuple[object, object]:

@@ -92,6 +92,28 @@ NVLINK_B300 = HwProfile(
 )
 
 
+# device_kind, as JAX reports it -> the profile of that chip's interconnect
+HW_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_for_device(device=None) -> HwProfile:
+    """The :class:`HwProfile` of ``device`` (default: the first JAX
+    device), looked up by ``device_kind``.  The CPU runs under
+    ``TPU_V5E``, the named rehearsal profile; an accelerator kind missing
+    from the table is an error, never a default."""
+    import jax
+    d = device if device is not None else jax.devices()[0]
+    if d.platform == "cpu":
+        return TPU_V5E
+    try:
+        return HW_BY_DEVICE_KIND[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model profile for device kind {d.device_kind!r} "
+            f"({d.platform}); known kinds: {sorted(HW_BY_DEVICE_KIND)}"
+        ) from None
+
+
 def _interp_log2(table: Dict[int, float], size_bytes: float) -> float:
     ks = sorted(table)
     x = math.log2(max(size_bytes, 1) / (1 << 20))

@@ -92,14 +92,13 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core import faults as _faults
 from ..core.context import (Algo, AxisKind, CollType, PROFILER_CONTEXT,
                             Proto, make_ctx)
 from ..core.maps import RingView
 from ..core.runtime import PolicyRuntime, global_runtime
 from . import algorithms as alg
-from .cost_model import CostModel, HwProfile, TPU_V5E
+from .cost_model import CostModel, HwProfile, hw_for_device
 
 SENTINEL_COST = 1e9
 MAX_CHANNELS = 32
@@ -142,7 +141,7 @@ def _decode_decision(raw: bytes) -> "Decision":
 
 @dataclasses.dataclass
 class DispatchConfig:
-    hw: HwProfile = TPU_V5E
+    hw: HwProfile = dataclasses.field(default_factory=hw_for_device)
     default_algo: int = Algo.DEFAULT
     default_proto: int = Proto.SIMPLE
     default_channels: int = 8
@@ -676,16 +675,17 @@ class CollectiveDispatcher:
         Returns ``(selector, state)``: an
         :class:`~repro.collectives.ingraph.InGraphSelector` compiled from
         the highest-precedence attached tuner program (``tier="pallas"``
-        for the single-kernel lowering, ``"pallas32"`` for the same
-        kernel in the Mosaic-ready 32-bit-pair representation — no x64
-        scope anywhere — and ``"jaxc"`` for the pure-JAX one) plus
+        for the single-kernel lowering at the backend's word width,
+        ``"pallas32"`` for the same kernel in the Mosaic-ready
+        32-bit-pair representation — no x64 scope anywhere — and
+        ``"jaxc"`` for the pure-JAX one) plus
         device-resident map state seeded from THIS runtime's live maps —
         host-accumulated telemetry moves in-graph, and from then on
         decisions run inside the compiled step with zero host
         round-trips and zero retraces.  Thread ``state`` through the
         step function; :func:`repro.core.jaxc.array_to_map`
-        (:func:`repro.core.lower32.array32_to_map` for ``pallas32``
-        state) writes it back to the host maps if host observers need
+        (:func:`repro.core.lower32.array32_to_map` when the selector's
+        ``word_width`` is 32) writes it back to the host maps if host observers need
         it."""
         from .ingraph import InGraphSelector
         lp = self.runtime.attached("tuner")
@@ -723,7 +723,7 @@ class CollectiveDispatcher:
     # ------------------------------------------------------------------
     def _dispatch(self, coll: int, x, axis_name: str, axis_kind: int,
                   **kw):
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         if n == 1 and coll in (CollType.ALL_REDUCE,):
             return x
         size_bytes = int(x.size) * x.dtype.itemsize

@@ -10,12 +10,11 @@ and ZERO host round-trips.
 
 Three in-graph tiers share this entry point: ``tier="jaxc"`` (pure-JAX
 if-conversion), ``tier="pallas"`` (the same CFG lowering packaged as one
-``pl.pallas_call`` kernel with VMEM-resident state — zero host marginal
-cost on-TPU), and ``tier="pallas32"`` (the kernel in the Mosaic-ready
-32-bit-pair representation: every u64 as a (lo, hi) uint32 pair, no x64
-scope anywhere — the form hardware Mosaic can actually lower).  All carry
-the array-map state as operands, so closed-loop adaptation keeps zero
-retraces either way.
+``pl.pallas_call`` kernel, at the backend's word width), and
+``tier="pallas32"`` (the kernel in the 32-bit-pair representation: every
+u64 as a (lo, hi) uint32 pair with its state in SMEM, no x64 scope
+anywhere — the form Mosaic lowers on TPU).  All carry the map state as
+operands, so closed-loop adaptation keeps zero retraces either way.
 
 Usage:
     sel = InGraphSelector(policy_program, tier="pallas32")
@@ -32,9 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size, maybe_x64
 from ..core.context import Algo, CollType, POLICY_CONTEXT, Proto
-from ..core.jaxc import compile_jax, map_to_array
+from ..core.jaxc import compile_jax, map_to_array, maybe_x64
 from ..core.lower32 import map_to_array32
 from ..core.maps import MapRegistry
 from ..core.program import Program
@@ -78,16 +76,14 @@ class InGraphSelector:
         vinfo = verify_with_info(program)
         self.program = program
         self.tier = tier
-        if tier == "pallas32":
-            from ..core.pallasc import compile_pallas
-            self._fn, self.map_names = compile_pallas(program, vinfo,
-                                                      word_width=32)
-            self.word_width = 32
-        elif tier == "pallas":
-            from ..core.pallasc import compile_pallas
-            self._fn, self.map_names = compile_pallas(program, vinfo,
-                                                      word_width=64)
-            self.word_width = 64
+        if tier in ("pallas", "pallas32"):
+            from ..core.pallasc import compile_pallas, resolve_word_width
+            # "pallas" takes the backend's word width: the pair form on
+            # TPU, where Mosaic has no 64-bit integer units
+            self.word_width = 32 if tier == "pallas32" \
+                else resolve_word_width(None)
+            self._fn, self.map_names = compile_pallas(
+                program, vinfo, word_width=self.word_width)
         else:
             self._fn, self.map_names = compile_jax(program, vinfo)
             self.word_width = 64
@@ -276,7 +272,7 @@ class InGraphSelector:
                    comm_id: int = 0, latency_ns=None):
         """Policy-selected all-reduce via lax.switch (all branches lowered
         once; selection is a runtime scalar)."""
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         algo, ch, state = self.decide(
             state, coll=CollType.ALL_REDUCE,
             msg_bytes=int(x.size) * x.dtype.itemsize, n=n,

@@ -37,13 +37,12 @@ map_update_elem / ema_update / ringbuf_reserve / ringbuf_submit /
 ringbuf_discard.  Wall-clock helpers are host-tier-only.
 
 We pass ctx and maps as uint64 arrays under the scoped 64-bit context
-(``repro.compat.enable_x64``); the surrounding model code stays 32-bit.
-On the jax 0.4.x line the x64 scope must also wrap the *outer* jit call
-boundary (see tests/test_jaxc.py) so inputs are not canonicalized down.
+(``jax.enable_x64``); the surrounding model code stays 32-bit.
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -51,7 +50,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import helpers as H
-from ..compat import enable_x64
 from .cfg import CFG, Loop
 from .isa import (FP_REG, Insn, STACK_SIZE, alu_base, alu_width, is_alu,
                   is_imm_form, is_jump_cond, is_load, is_store, jump_base,
@@ -97,8 +95,8 @@ def check_supported(prog: Program, *, word_width: int = 64) -> None:
                 f"policy '{prog.name}' uses lru_hash map(s) "
                 f"{', '.join(repr(n) for n in lru)}; the 32-bit-pair tier "
                 "does not lower LRU recency/clock metadata.  Workarounds: "
-                "declare the map with kind=\"hash\", keep word_width=64, "
-                "or run on a host tier (interp/jit/native)")
+                "declare the map with kind=\"hash\" or run on a host tier "
+                "(interp/jit/native); off the TPU, word_width=64 lowers it")
     for d in prog.maps:
         if d.kind not in _INGRAPH_KINDS:
             raise JaxcError(
@@ -166,6 +164,14 @@ def written_map_names(prog: Program, vinfo) -> frozenset:
     return frozenset(out)
 
 
+def maybe_x64(needed: bool = True):
+    """``jax.enable_x64(True)`` scope when ``needed``, a no-op otherwise:
+    callers that serve both word widths scope with
+    ``maybe_x64(word_width == 64)``, so the 32-bit-pair path never drags
+    the x64 machinery in."""
+    return jax.enable_x64(True) if needed else contextlib.nullcontext()
+
+
 def _u64(x):
     return jnp.asarray(x, jnp.uint64)
 
@@ -226,6 +232,15 @@ class _Lowerer:
     def _fresh_stack(self):
         """A zeroed frame in the machine representation (u64 slots)."""
         return jnp.zeros(STACK_SIZE // 8, jnp.uint64)
+
+    def _enter_frame(self):
+        """Give an inlined callee a fresh zeroed frame; returns the token
+        :meth:`_leave_frame` restores the caller's frame from."""
+        saved, self.stack = self.stack, self._fresh_stack()
+        return saved
+
+    def _leave_frame(self, saved) -> None:
+        self.stack = saved
 
     def _imm(self, imm: int):
         """Materialize a 64-bit immediate in the machine representation."""
@@ -644,12 +659,11 @@ class _Lowerer:
         whole program one straight trace: zero retraces, and loops
         containing calls still lower to a single ``fori_loop``."""
         callee = self.fns[1 + idx]
-        saved = (self.fninfo, self.cfg, self.insns, self.stack,
+        saved = (self.fninfo, self.cfg, self.insns, self._enter_frame(),
                  self.regs, self.done, self.ret)
         self.fninfo = callee
         self.cfg = callee.cfg
         self.insns = list(callee.insns)
-        self.stack = self._fresh_stack()
         cregs = [self._imm(0)] * 11
         for r in (1, 2, 3, 4, 5):
             cregs[r] = saved[4][r]
@@ -666,8 +680,9 @@ class _Lowerer:
                 f"unrouted edges in subprogram '{callee.name}': "
                 f"{sorted(out)}")
         ret = self.ret
-        (self.fninfo, self.cfg, self.insns, self.stack,
+        (self.fninfo, self.cfg, self.insns, frame,
          self.regs, self.done, self.ret) = saved
+        self._leave_frame(frame)
         self._wreg(P, 0, ret)
         for r in (1, 2, 3, 4, 5):
             self._wreg(P, r, self._imm(0))
@@ -744,7 +759,7 @@ def compile_jax(prog: Program, vinfo=None):
         vinfo = verify_with_info(prog)
 
     def run(ctx_vec, map_arrays: Dict[str, jnp.ndarray]):
-        with enable_x64(True):
+        with jax.enable_x64(True):
             return _Lowerer(prog, vinfo, ctx_vec, map_arrays).run()
 
     return run, [d.name for d in prog.maps]
@@ -828,7 +843,7 @@ def map_to_array(m: BpfMap) -> jnp.ndarray:
         out = m.to_device()
     except MapError as e:
         raise JaxcError(str(e)) from None
-    with enable_x64(True):
+    with jax.enable_x64(True):
         return jnp.asarray(out)
 
 
@@ -840,7 +855,7 @@ def array_to_map(arr, m: BpfMap) -> None:
 
 def ctx_to_vec(ctx_buf: bytearray) -> jnp.ndarray:
     import numpy as np
-    with enable_x64(True):
+    with jax.enable_x64(True):
         return jnp.asarray(np.frombuffer(bytes(ctx_buf), dtype="<u8"))
 
 

@@ -1,8 +1,8 @@
 """lower32 — the 32-bit-pair lowering of the u64 policy machine.
 
-Mosaic (the TPU Pallas backend) has no native 64-bit integer ops, so the
-uint64 lowering in :mod:`repro.core.jaxc` only compiles on real TPUs via
-x64 emulation or interpret mode.  This module re-represents EVERY u64
+Mosaic (the TPU Pallas backend) has no 64-bit integer ops, so the uint64
+lowering in :mod:`repro.core.jaxc` cannot become a TPU kernel (it runs
+as plain XLA ops, or in interpret mode).  This module re-represents EVERY u64
 machine value — registers, stack slots, ctx fields, array-map slots, the
 return value — as a ``(lo, hi)`` pair of uint32, with the full u64
 semantics synthesized from 32-bit ops:
@@ -23,14 +23,15 @@ semantics synthesized from 32-bit ops:
 The control-flow machinery (predicated regions, ``lax.fori_loop`` loop
 carries, exit-predicate routing) is inherited unchanged from
 :class:`repro.core.jaxc._Lowerer`; only the representation hooks are
-overridden.  Loads verify exactly once: ``compile_jax32`` reuses the same
-``verify_with_info`` artifacts as every other tier.
+overridden, and ctx, stack and map state live in flat uint32 refs (see
+:class:`_Lowerer32`).  Loads verify exactly once: ``compile_jax32``
+reuses the same ``verify_with_info`` artifacts as every other tier.
 
 Array layout convention (host <-> device, little-endian friendly):
 the trailing axis holds ``[lo, hi]`` — a uint64 array viewed as ``<u4``
 yields exactly this layout, so host conversion is a reinterpret, not a
-shuffle.  ctx is uint32[n_fields, 2], array maps are
-uint32[max_entries, value_slots, 2], the return value is uint32[2].
+shuffle.  ctx is uint32[n_fields, 2], maps are uint32[rows, cols, 2]
+(:func:`repro.core.maps.device_shape`), the return value is uint32[2].
 
 None of this path touches the x64 scope: it traces, jits, and executes
 with jax's default 32-bit types enabled only.
@@ -47,7 +48,7 @@ from jax import lax
 from .isa import FP_REG, STACK_SIZE, Insn, mem_size
 from .jaxc import (JaxcError, _CTX_TAG, _Lowerer, _STACK_TAG, _map_tag,
                    check_supported)
-from .maps import BpfMap
+from .maps import BpfMap, device_shape
 from .program import Program
 from .verifier import verify_with_info
 
@@ -263,30 +264,99 @@ def _alu32_pair(base: str, a: Pair, b: Pair) -> Pair:
 
 
 # ---------------------------------------------------------------------------
-# The lowerer: jaxc's CFG walk over the pair representation
+# The lowerer: jaxc's CFG walk over the pair representation, state in refs
 # ---------------------------------------------------------------------------
+
+FRAME_WORDS = STACK_SIZE // 8 * 2     # one stack frame: 64 slots x [lo, hi]
+
+
+def _get(ref, i) -> Pair:
+    """The pair at flat word index ``i`` (lo at ``i``, hi at ``i + 1``)."""
+    return (ref[i], ref[i + 1])
+
+
+def _put(ref, i, p, val: Pair) -> None:
+    """Predicated pair store: the cell keeps its old value where ``p`` is
+    false, so a write in a block the program did not take is a no-op."""
+    lo, hi = ref[i], ref[i + 1]
+    ref[i] = jnp.where(p, val[0], lo)
+    ref[i + 1] = jnp.where(p, val[1], hi)
+
+
+def _clip(i, n: int):
+    """Clamp a traced index into ``[0, n)``.  Blocks run under a false
+    predicate still compute their addresses from whatever the registers
+    hold; clamping keeps those dead accesses inside the buffer (the
+    selects discard what they read, and their stores write the old
+    value back)."""
+    return jnp.clip(i, 0, n - 1)
+
+
+def n_frames(prog: Program) -> int:
+    """Stack frames the inlined call tree can hold live at once: the main
+    frame plus one per subprogram (the verifier rejects recursion, so no
+    call path visits a subprogram twice)."""
+    return 1 + len(prog.subprogs)
+
 
 class _Lowerer32(_Lowerer):
     """`_Lowerer` with every machine value as a (lo, hi) uint32 pair.
 
-    Inherits the region/loop machinery verbatim — the snapshot/restore
-    loop carries thread tuples of pairs through ``lax.fori_loop`` exactly
-    like tuples of uint64 scalars."""
+    Registers, ``done`` and ``ret`` are traced scalars threaded through
+    the ``fori_loop`` carries exactly as in the base class.  ctx, stack
+    frames and map state live in flat uint32 REFS (``[lo, hi]`` word
+    pairs): every access is a scalar load or store at a static or
+    clamped dynamic index.  That is the form Mosaic lowers (SMEM refs,
+    scalar-core arithmetic, ``scf.for``/``scf.while``) — no scatter, no
+    gather, no dynamic slice, no 64-bit lane.  Outside a kernel,
+    :func:`compile_jax32` runs the same body under ``pl.run_state``,
+    which turns the ref effects back into pure array updates."""
+
+    def __init__(self, prog: Program, vinfo, ctx_ref, map_refs, stack_ref):
+        self._refs = (ctx_ref, dict(map_refs), stack_ref)
+        super().__init__(prog, vinfo, None, None)
 
     # ---- representation hooks -------------------------------------------
     def _init_state(self, ctx_vec, map_arrays) -> None:
-        self.ctx = jnp.asarray(ctx_vec, jnp.uint32)          # [fields, 2]
-        self.maps = {k: jnp.asarray(v, jnp.uint32)           # [n, slots, 2]
-                     for k, v in map_arrays.items()}
+        self.ctx, self.maps, self.stack = self._refs
+        self.shapes = {d.name: device_shape(d.kind, d.value_size,
+                                            d.max_entries)
+                       for d in self.decls}
+        self.frame = 0
+        self._zero_frame()
         self.regs = [pair_const(0)] * 11
         self.regs[1] = pair_const(_CTX_TAG)
         self.regs[FP_REG] = pair_const(_STACK_TAG | STACK_SIZE)
-        self.stack = self._fresh_stack()
         self.done = jnp.asarray(False)
         self.ret = pair_const(0)
 
-    def _fresh_stack(self):
-        return jnp.zeros((STACK_SIZE // 8, 2), jnp.uint32)
+    def _zero_frame(self) -> None:
+        base, ref = self.frame * FRAME_WORDS, self.stack
+
+        def body(i, c):
+            ref[base + i] = jnp.uint32(0)
+            return c
+        lax.fori_loop(0, FRAME_WORDS, body, 0)
+
+    def _enter_frame(self):
+        self.frame += 1
+        self._zero_frame()
+        return None
+
+    def _leave_frame(self, saved) -> None:
+        self.frame -= 1
+
+    def _snapshot(self, active, exit_preds):
+        # ctx / stack / maps are refs, updated in place by the loop body
+        return (active, tuple(self.regs), self.done, self.ret,
+                tuple(exit_preds))
+
+    def _restore(self, carry):
+        active, regs, done, ret, exps = carry
+        self.regs = list(regs)
+        self.done = done
+        self.ret = ret
+        return active, list(exps)
 
     def _imm(self, imm: int) -> Pair:
         return pair_const(imm)
@@ -306,10 +376,19 @@ class _Lowerer32(_Lowerer):
     def _cmp(self, base: str, a: Pair, b: Pair):
         return pair_cmp(base, a, b)
 
+    # ---- addressing --------------------------------------------------------
+    def _cell(self, name: str, row, col):
+        """Flat word index of map ``name``'s cell ``(row, col)``."""
+        return (row * self.shapes[name][1] + col) * 2
+
+    def _slot(self, ptr: Pair):
+        """Flat word index of the stack slot ``ptr`` addresses."""
+        slot = _clip((ptr[0] >> 3).astype(jnp.int32), STACK_SIZE // 8)
+        return self.frame * FRAME_WORDS + slot * 2
+
     # ---- memory ----------------------------------------------------------
     def _stack_load(self, ptr: Pair, size: int):
-        slot = (ptr[0] >> 3).astype(jnp.int32)   # lo lane holds the offset
-        word: Pair = (self.stack[slot, 0], self.stack[slot, 1])
+        word = _get(self.stack, self._slot(ptr))
         if size == 8:
             return word
         sh = (ptr[0] & 7) * 8
@@ -317,27 +396,27 @@ class _Lowerer32(_Lowerer):
         return (shifted[0] & _u32((1 << (8 * size)) - 1), jnp.uint32(0))
 
     def _stack_store(self, P, ptr: Pair, size: int, val: Pair) -> None:
-        slot = (ptr[0] >> 3).astype(jnp.int32)
-        word: Pair = (self.stack[slot, 0], self.stack[slot, 1])
+        i = self._slot(ptr)
         if size == 8:
             new = val
         else:
+            word = _get(self.stack, i)
             mask = (1 << (8 * size)) - 1
             sh: Pair = ((ptr[0] & 7) * 8, jnp.uint32(0))
             hole = pair_lsh(pair_const(mask), sh)
             piece = pair_lsh((val[0] & _u32(mask), jnp.uint32(0)), sh)
             new = ((word[0] & ~hole[0]) | piece[0],
                    (word[1] & ~hole[1]) | piece[1])
-        sel = pair_select(P, new, word)
-        self.stack = self.stack.at[slot].set(jnp.stack([sel[0], sel[1]]))
+        _put(self.stack, i, P, new)
 
-    @staticmethod
-    def _mapval_decode(ptr: Pair):
+    def _mapval_cell(self, mname: str, ptr: Pair):
+        """Flat word index of the map value slot a value pointer names:
+        ``ptr`` encodes ``(16 + mi) << 56 | row << 24 | byte_off``."""
         lo, hi = ptr
-        mi = ((hi >> 24) - 16).astype(jnp.int32)
-        key = ((hi << 8) | (lo >> 24)).astype(jnp.int32)
-        off = lo & 0xFFFFFF
-        return mi, key, off
+        rows, cols = self.shapes[mname]
+        row = _clip(((hi << 8) | (lo >> 24)).astype(jnp.int32), rows)
+        col = _clip(((lo & 0xFFFFFF) >> 3).astype(jnp.int32), cols)
+        return self._cell(mname, row, col)
 
     def _exec_load(self, pc: int, insn: Insn, P) -> None:
         size = mem_size(insn.op)
@@ -345,18 +424,13 @@ class _Lowerer32(_Lowerer):
         ptr = pair_add(self.regs[insn.src], pair_const(insn.off & M64))
         if region == "ctx":
             off = base + insn.off            # static (verified)
-            val: Pair = (self.ctx[off // 8, 0], self.ctx[off // 8, 1])
-            if size < 8:
-                val = (val[0] & _u32((1 << (8 * size)) - 1), jnp.uint32(0))
+            val = _get(self.ctx, off // 8 * 2)
         elif region == "stack":
             val = self._stack_load(ptr, size)
         else:  # mapval
-            _, key, off = self._mapval_decode(ptr)
-            slot = (off >> 3).astype(jnp.int32)
-            val = (self.maps[mname][key, slot, 0],
-                   self.maps[mname][key, slot, 1])
-            if size < 8:
-                val = (val[0] & _u32((1 << (8 * size)) - 1), jnp.uint32(0))
+            val = _get(self.maps[mname], self._mapval_cell(mname, ptr))
+        if region != "stack" and size < 8:
+            val = (val[0] & _u32((1 << (8 * size)) - 1), jnp.uint32(0))
         self._wreg(P, insn.dst, val)
 
     def _exec_store(self, pc: int, insn: Insn, P) -> None:
@@ -366,22 +440,27 @@ class _Lowerer32(_Lowerer):
             if not insn.op.startswith("stx") else self.regs[insn.src]
         ptr = pair_add(self.regs[insn.dst], pair_const(insn.off & M64))
         if region == "ctx":
-            slot = (base + insn.off) // 8
-            old: Pair = (self.ctx[slot, 0], self.ctx[slot, 1])
-            sel = pair_select(P, val, old)
-            self.ctx = self.ctx.at[slot].set(jnp.stack([sel[0], sel[1]]))
+            _put(self.ctx, (base + insn.off) // 8 * 2, P, val)
         elif region == "stack":
             self._stack_store(P, ptr, size, val)
         else:  # mapval
-            _, key, off = self._mapval_decode(ptr)
-            slot = (off >> 3).astype(jnp.int32)
-            old = (self.maps[mname][key, slot, 0],
-                   self.maps[mname][key, slot, 1])
-            sel = pair_select(P, val, old)
-            self.maps[mname] = self.maps[mname].at[key, slot].set(
-                jnp.stack([sel[0], sel[1]]))
+            _put(self.maps[mname], self._mapval_cell(mname, ptr), P, val)
 
     # ---- helpers ---------------------------------------------------------
+    def _ema(self, old: Pair) -> Pair:
+        """``ema_update``'s ``(old * (w - 1) + sample) // w``, w >= 1."""
+        one = pair_const(1)
+        w = pair_select(pair_cmp("jgt", self.regs[4], one),
+                        self.regs[4], one)
+        acc = pair_add(pair_mul(old, pair_sub(w, one)), self.regs[3])
+        return pair_divmod(acc, w)[0]
+
+    def _value_row(self, n_slots: int):
+        """The ``n_slots`` u64 words ``map_update_elem``'s value* (r3)
+        points at on the stack."""
+        return [self._stack_load(pair_add(self.regs[3], pair_const(8 * s)), 8)
+                for s in range(n_slots)]
+
     def _call(self, pc: int, insn: Insn, P) -> Pair:
         hid = insn.imm
         mname = self.fninfo.call_map.get(pc)
@@ -393,6 +472,7 @@ class _Lowerer32(_Lowerer):
             return self._call_ringbuf32(hid, mi, d, P)
         if d.kind == "hash":
             return self._call_hash32(hid, mi, d, P)
+        ref = self.maps[d.name]
         key = self._stack_load(self.regs[2], d.key_size)   # hi lane is 0
         valid = key[0] < jnp.uint32(d.max_entries)
         ki = jnp.minimum(key[0], jnp.uint32(d.max_entries - 1)).astype(
@@ -402,54 +482,61 @@ class _Lowerer32(_Lowerer):
             shifted = pair_lsh(key, pair_const(24))
             enc: Pair = (tag[0] | shifted[0], tag[1] | shifted[1])
             return pair_select(valid, enc, pair_const(0))
+        take = jnp.logical_and(P, valid)
         if hid == 2:  # map_update_elem(map, key*, value*, flags)
-            n_slots = d.value_size // 8
-            rows = [self._stack_load(
-                pair_add(self.regs[3], pair_const(8 * s)), 8)
-                for s in range(n_slots)]
-            newrow = jnp.stack([jnp.stack([lo, hi]) for lo, hi in rows])
-            old = self.maps[d.name][ki]
-            take = jnp.logical_and(P, valid)
-            self.maps[d.name] = self.maps[d.name].at[ki].set(
-                jnp.where(take, newrow, old))
+            for s, val in enumerate(self._value_row(d.value_size // 8)):
+                _put(ref, self._cell(d.name, ki, s), take, val)
             return pair_select(valid, pair_const(0), pair_const(M64))
         if hid == 64:  # ema_update(map, key*, sample, weight)
-            one = pair_const(1)
-            w = pair_select(pair_cmp("jgt", self.regs[4], one),
-                            self.regs[4], one)
-            old = (self.maps[d.name][ki, 0, 0], self.maps[d.name][ki, 0, 1])
-            acc = pair_add(pair_mul(old, pair_sub(w, one)), self.regs[3])
-            new = pair_divmod(acc, w)[0]
-            take = jnp.logical_and(P, valid)
-            sel = pair_select(take, new, old)
-            self.maps[d.name] = self.maps[d.name].at[ki, 0].set(
-                jnp.stack([sel[0], sel[1]]))
+            i = self._cell(d.name, ki, 0)
+            new = self._ema(_get(ref, i))
+            _put(ref, i, take, new)
             return new
         raise JaxcError(f"helper {hid} not supported in-graph")
 
-    def _call_hash32(self, hid: int, mi: int, d, P) -> Pair:
-        """Pair-form open-addressing probe (see ``_Lowerer._call_hash``
-        for the layout and termination argument).  ``hash_slot`` folds
-        the key to 32 bits (``lo ^ hi``), so locating the probe origin
-        costs one uint32 modulo — no pair division anywhere on the scan,
-        and key equality is a two-lane compare."""
-        arr = self.maps[d.name]
+    def _hash_probe32(self, d, key: Pair):
+        """Pair-form open-addressing probe (see ``_Lowerer._hash_probe``
+        for the layout and termination argument), as a scalar scan in
+        probe order from the home slot that stops at the first key match
+        or empty row.  ``hash_slot`` folds the key to 32 bits
+        (``lo ^ hi``), so locating the probe origin costs one uint32
+        modulo, and key equality is a two-lane compare.
+
+        Returns ``(first, hit, can_claim)``; a full table with the key
+        absent scans every row and reports row 0, no hit, no claim."""
+        ref, name = self.maps[d.name], d.name
         slots = d.value_size // 8
-        kcol, ucol = slots, slots + 1
         cap = d.max_entries
+        h = ((key[0] ^ key[1]) % jnp.uint32(cap)).astype(jnp.int32)
+
+        def row_state(row):
+            klo, khi = _get(ref, self._cell(name, row, slots))
+            ulo, uhi = _get(ref, self._cell(name, row, slots + 1))
+            used = (ulo | uhi) > 0
+            return used, used & (klo == key[0]) & (khi == key[1])
+
+        def cond(c):
+            k, stopped, _ = c
+            return jnp.logical_and(k < cap, jnp.logical_not(stopped))
+
+        def body(c):
+            k, _, _ = c
+            row = h + k
+            row = jnp.where(row >= cap, row - cap, row)
+            used, match = row_state(row)
+            return k + 1, match | jnp.logical_not(used), row
+
+        _, has_stop, row = lax.while_loop(
+            cond, body, (jnp.int32(0), jnp.asarray(False), jnp.int32(0)))
+        first = jnp.where(has_stop, row, 0)
+        hit = jnp.logical_and(has_stop, row_state(first)[1])
+        return first, hit, jnp.logical_and(has_stop, jnp.logical_not(hit))
+
+    def _call_hash32(self, hid: int, mi: int, d, P) -> Pair:
+        ref, name = self.maps[d.name], d.name
+        slots = d.value_size // 8
         key = self._stack_load(self.regs[2], d.key_size)   # Pair
-        keys_lo = arr[:cap, kcol, 0]
-        keys_hi = arr[:cap, kcol, 1]
-        used = (arr[:cap, ucol, 0] | arr[:cap, ucol, 1]) > 0
-        h = (key[0] ^ key[1]) % jnp.uint32(cap)
-        dist = (jnp.arange(cap, dtype=jnp.uint32) - h) % jnp.uint32(cap)
-        is_match = used & (keys_lo == key[0]) & (keys_hi == key[1])
-        stop = is_match | jnp.logical_not(used)
-        first = jnp.argmin(
-            jnp.where(stop, dist, jnp.uint32(cap))).astype(jnp.int32)
-        has_stop = jnp.any(stop)
-        hit = jnp.logical_and(has_stop, is_match[first])
-        can_claim = jnp.logical_and(has_stop, jnp.logical_not(hit))
+        first, hit, can_claim = self._hash_probe32(d, key)
         if hid == 1:  # map_lookup_elem: encode the physical row index
             tag = pair_const(_map_tag(mi))
             row: Pair = (first.astype(jnp.uint32), jnp.uint32(0))
@@ -457,80 +544,55 @@ class _Lowerer32(_Lowerer):
             enc: Pair = (tag[0] | sh[0], tag[1] | sh[1])
             return pair_select(hit, enc, pair_const(0))
         ok = jnp.logical_or(hit, can_claim)
-        oldrow = lax.dynamic_slice(
-            arr, (first, jnp.int32(0), jnp.int32(0)),
-            (1, arr.shape[1], 2))[0]
         if hid == 2:  # map_update_elem: overwrite hit else claim a slot
-            vals = [self._stack_load(
-                pair_add(self.regs[3], pair_const(8 * s)), 8)
-                for s in range(slots)]
-            newvals = jnp.stack([jnp.stack([lo, hi]) for lo, hi in vals])
+            newvals = self._value_row(slots)
             ret = pair_select(ok, pair_const(0), pair_const(M64))
         elif hid == 64:  # ema_update: RMW slot 0 (miss seeds from old=0)
-            one = pair_const(1)
-            w = pair_select(pair_cmp("jgt", self.regs[4], one),
-                            self.regs[4], one)
-            old: Pair = (jnp.where(hit, oldrow[0, 0], jnp.uint32(0)),
-                         jnp.where(hit, oldrow[0, 1], jnp.uint32(0)))
-            acc = pair_add(pair_mul(old, pair_sub(w, one)), self.regs[3])
-            new = pair_divmod(acc, w)[0]
-            keep = jnp.where(hit, oldrow[:slots],
-                             jnp.zeros((slots, 2), jnp.uint32))
-            newvals = keep.at[0].set(jnp.stack([new[0], new[1]]))
-            ret = new
+            zero = pair_const(0)
+            old = [pair_select(hit, _get(ref, self._cell(name, first, s)),
+                               zero) for s in range(slots)]
+            ret = self._ema(old[0])
+            newvals = [ret] + old[1:]
         else:
             raise JaxcError(f"helper {hid} on hash map '{d.name}'")
         take = jnp.logical_and(P, ok)
-        tail = jnp.stack([jnp.stack([key[0], key[1]]),
-                          jnp.stack([jnp.uint32(1), jnp.uint32(0)])])
-        full_new = jnp.concatenate([newvals, tail])
-        sel = jnp.where(take, full_new, oldrow)
-        arr = lax.dynamic_update_slice(
-            arr, sel[None], (first, jnp.int32(0), jnp.int32(0)))
-        occ: Pair = (arr[cap, 0, 0], arr[cap, 0, 1])
-        occ1 = pair_select(jnp.logical_and(P, can_claim),
-                           pair_add(occ, pair_const(1)), occ)
-        arr = arr.at[cap, 0].set(jnp.stack([occ1[0], occ1[1]]))
-        self.maps[d.name] = arr
+        for s, val in enumerate(newvals + [key, pair_const(1)]):
+            _put(ref, self._cell(name, first, s), take, val)
+        occ_i = self._cell(name, d.max_entries, 0)
+        _put(ref, occ_i, jnp.logical_and(P, can_claim),
+             pair_add(_get(ref, occ_i), pair_const(1)))
         return ret
 
     def _call_ringbuf32(self, hid: int, mi: int, d, P) -> Pair:
         """reserve/submit/discard over the device layout's control words,
         with the free-running u64 cursors held as (lo, hi) pairs — the
         carry chains keep cursor arithmetic exact past 2**32 events."""
-        arr = self.maps[d.name]
+        ref = self.maps[d.name]
         slots = d.value_size // 8
-        ctl = lambda w: (d.max_entries + w // slots, w % slots)  # noqa: E731
-        (hr, hc), (pr, pc2) = ctl(0), ctl(3)
-        head: Pair = (arr[hr, hc, 0], arr[hr, hc, 1])
-        pend: Pair = (arr[pr, pc2, 0], arr[pr, pc2, 1])
 
-        def put(r, c, pair: Pair) -> None:
-            self.maps[d.name] = self.maps[d.name].at[r, c].set(
-                jnp.stack([pair[0], pair[1]]))
+        def ctl(w):
+            return self._cell(d.name, d.max_entries + w // slots, w % slots)
 
+        hi_, ti_, di_, pi_ = ctl(0), ctl(1), ctl(2), ctl(3)
+        head, tail = _get(ref, hi_), _get(ref, ti_)
+        drops, pend = _get(ref, di_), _get(ref, pi_)
         if hid == 66:  # ringbuf_submit
-            head2 = pair_add(head, pend)
-            put(hr, hc, pair_select(P, head2, head))
-            put(pr, pc2, pair_select(P, pair_const(0), pend))
+            _put(ref, hi_, P, pair_add(head, pend))
+            _put(ref, pi_, P, pair_const(0))
             return pair_const(0)
         if hid == 67:  # ringbuf_discard
-            put(pr, pc2, pair_select(P, pair_const(0), pend))
+            _put(ref, pi_, P, pair_const(0))
             return pair_const(0)
         if hid != 65:
             raise JaxcError(f"helper {hid} on ringbuf map '{d.name}'")
         # ringbuf_reserve: implicit commit, then NULL (+1 drop) on full
-        (tr, tc), (dr, dc) = ctl(1), ctl(2)
-        tail: Pair = (arr[tr, tc, 0], arr[tr, tc, 1])
-        drops: Pair = (arr[dr, dc, 0], arr[dr, dc, 1])
         head1 = pair_add(head, pend)
         full = pair_cmp("jge", pair_sub(head1, tail),
                         pair_const(d.max_entries))
-        put(hr, hc, pair_select(P, head1, head))
-        put(pr, pc2, pair_select(
-            P, pair_select(full, pair_const(0), pair_const(1)), pend))
-        put(dr, dc, pair_select(jnp.logical_and(P, full),
-                                pair_add(drops, pair_const(1)), drops))
+        _put(ref, hi_, P, head1)
+        _put(ref, pi_, P, pair_select(full, pair_const(0), pair_const(1)))
+        _put(ref, di_, jnp.logical_and(P, full),
+             pair_add(drops, pair_const(1)))
         row = pair_divmod(head1, pair_const(d.max_entries))[1]
         tag = pair_const(_map_tag(mi))
         sh = pair_lsh(row, pair_const(24))
@@ -538,33 +600,51 @@ class _Lowerer32(_Lowerer):
         return pair_select(full, pair_const(0), enc)
 
 
+def run_on_refs(prog: Program, vinfo, ctx_ref, map_refs, stack_ref,
+                ret_ref) -> None:
+    """Run one decision against flat uint32 refs: ``ctx_ref`` and each
+    ``map_refs[name]`` are updated in place, ``ret_ref[0:2]`` receives
+    the return value, ``stack_ref`` is scratch of
+    ``n_frames(prog) * FRAME_WORDS`` words.  The kernel body of the
+    ``pallas32`` tier and of :func:`compile_jax32`."""
+    ret = _Lowerer32(prog, vinfo, ctx_ref, map_refs, stack_ref).run()[0]
+    ret_ref[0] = ret[0]
+    ret_ref[1] = ret[1]
+
+
 def compile_jax32(prog: Program, vinfo=None):
     """Return (fn, map_names) in the pair calling convention.
 
     ``fn(ctx_vec32, map_arrays32) -> (ret32, ctx32_out, map_arrays32_out)``
     where ``ctx_vec32`` is uint32[n_fields, 2], each map array is
-    uint32[max_entries, value_slots, 2] (trailing axis = [lo, hi]) and
-    ``ret32`` is uint32[2].  Pure and jit-safe; runs with jax's default
-    32-bit types — no x64 scope anywhere.
+    uint32[rows, cols, 2] (trailing axis = [lo, hi], rows/cols from
+    :func:`repro.core.maps.device_shape`) and ``ret32`` is uint32[2].
+    Pure and jit-safe: the ref-based body runs under ``pl.run_state``.
+    Runs with jax's default 32-bit types — no x64 scope anywhere.
 
     ``vinfo`` reuses a prior :func:`verify_with_info` result so the
     runtime's load path verifies exactly once across every tier."""
-    check_supported(prog)
-    for d in prog.maps:
-        if d.kind == "lru_hash":
-            raise JaxcError(
-                f"map '{d.name}' is lru_hash; the 32-bit-pair tier does "
-                "not lower LRU maps (pair-compare scans over recency "
-                "dominate the kernel) — use the pallas/jaxc or host tiers")
+    from jax.experimental import pallas as pl
+    check_supported(prog, word_width=32)
     if vinfo is None:
         vinfo = verify_with_info(prog)
+    names = [d.name for d in prog.maps]
 
     def run(ctx_vec32, map_arrays32: Dict[str, jnp.ndarray]):
-        ret, ctx, maps = _Lowerer32(prog, vinfo, ctx_vec32,
-                                    map_arrays32).run()
-        return jnp.stack([ret[0], ret[1]]), ctx, maps
+        ctx = jnp.asarray(ctx_vec32, jnp.uint32)
+        arrs = {n: jnp.asarray(map_arrays32[n], jnp.uint32) for n in names}
 
-    return run, [d.name for d in prog.maps]
+        def body(refs):
+            run_on_refs(prog, vinfo, *refs)
+
+        init = (ctx.reshape(-1), {n: a.reshape(-1) for n, a in arrs.items()},
+                jnp.zeros(n_frames(prog) * FRAME_WORDS, jnp.uint32),
+                jnp.zeros(2, jnp.uint32))
+        ctx_o, maps_o, _, ret = pl.run_state(body)(init)
+        return (ret, ctx_o.reshape(ctx.shape),
+                {n: maps_o[n].reshape(arrs[n].shape) for n in names})
+
+    return run, names
 
 
 # ---------------------------------------------------------------------------

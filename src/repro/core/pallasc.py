@@ -6,17 +6,16 @@ if-conversion fused into the step program).  jaxc already removed host
 round-trips, but its lowering emits free-floating jnp ops that XLA may
 schedule anywhere; this tier packages the whole verified decision —
 including PR 3's bounded loops — into a single :func:`pl.pallas_call`
-kernel with explicit BlockSpec/VMEM tiling, so on-TPU the policy runs as
-one fused kernel whose operands (ctx vector + array-map state) are
-VMEM-resident for the duration of the decision.  Host marginal cost per
-decision is zero: the host neither computes nor copies anything once the
-step is dispatched.
+kernel, so on TPU the policy runs as one Mosaic kernel whose operands
+(ctx vector + map state) stay in on-chip memory (SMEM) for the duration
+of the decision.  Host marginal cost per decision is zero: the host
+neither computes nor copies anything once the step is dispatched.
 
 Two word widths share the entry point:
 
   * ``word_width=64`` — the uint64 lowering
-    (:class:`repro.core.jaxc._Lowerer`).  Compiles through Mosaic only
-    via x64 emulation/interpret mode; needs the scoped x64 context.
+    (:class:`repro.core.jaxc._Lowerer`).  Runs in interpret mode only
+    (Mosaic has no 64-bit integer ops); needs the scoped x64 context.
   * ``word_width=32`` — the Mosaic-ready pair lowering
     (:class:`repro.core.lower32._Lowerer32`): every u64 register, stack
     slot, ctx field, and map slot is a ``(lo, hi)`` uint32 pair with
@@ -24,9 +23,7 @@ Two word widths share the entry point:
     compare chains.  No 64-bit integer op ever reaches the kernel, and
     no x64 scope is needed anywhere on the path.
 
-``word_width=None`` picks 64 when the build has a working x64 scope and
-falls back to 32 otherwise — builds where ``enable_x64`` is broken can
-still run the pallas tier through the pair representation.
+``word_width=None`` follows the backend: 32 on TPU, 64 elsewhere.
 
 Lowering path (shared with jaxc by construction): the verifier's
 artifacts — shared CFG, proven ``loop_bounds``, per-insn region info —
@@ -37,9 +34,8 @@ the runtime's single verify pass.
 
 Backends: on TPU the kernel compiles through Mosaic; on CPU (CI) the
 same ``pallas_call`` runs in interpret mode — identical lowering path,
-executed by the Pallas interpreter.  ``mode="jit"`` bypasses the kernel
-harness entirely and jits the bare lowering body (the pure-JAX fallback
-for builds without a working Pallas).
+executed by the Pallas interpreter.  ``mode="jit"``, asked for by name,
+bypasses the kernel harness and jits the bare lowering body.
 
 The host bridge (:class:`DeviceBridge`, returned by
 :func:`compile_host`) keeps map state DEVICE-RESIDENT across calls:
@@ -54,53 +50,44 @@ changes hands.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import enable_x64, maybe_x64
 from . import faults as _faults
 from .context import Algo, Proto
 from .jaxc import (JaxcError, _Lowerer, array_to_map, check_supported,
-                   compile_jax, ctx_to_vec, map_to_array, written_map_names)
-from .lower32 import (_Lowerer32, array32_to_map, compile_jax32,
-                      ctx_to_vec32, map_to_array32, ret32_to_int,
-                      vec32_to_bytes)
+                   compile_jax, ctx_to_vec, map_to_array, maybe_x64,
+                   written_map_names)
+from .lower32 import (FRAME_WORDS, array32_to_map, compile_jax32,
+                      ctx_to_vec32, map_to_array32, n_frames, ret32_to_int,
+                      run_on_refs, vec32_to_bytes)
 from .maps import BpfMap, device_shape
 from .program import Program
 from .verifier import verify_with_info
-
-try:  # pallas is present on every jax build we target, but stay graceful
-    from jax.experimental import pallas as pl
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover — exercised only on exotic builds
-    pl = None
-    HAVE_PALLAS = False
-
 
 class PallascError(Exception):
     pass
 
 
-def _resolve_mode(mode: Optional[str]) -> str:
-    if mode is None:
-        mode = "pallas" if HAVE_PALLAS else "jit"
-    if mode not in ("pallas", "jit"):
-        raise PallascError(f"unknown pallasc mode {mode!r}; "
-                           "use 'pallas' or 'jit'")
-    if mode == "pallas" and not HAVE_PALLAS:
-        raise PallascError("this jax build has no importable Pallas; "
-                           "use mode='jit' (the pure-JAX fallback)")
-    return mode
+def on_tpu() -> bool:
+    """True when the default backend is a TPU.  Kernels then compile
+    through Mosaic, and the in-graph tiers take the pair form."""
+    return jax.default_backend() == "tpu"
 
 
-def _resolve_word_width(word_width: Optional[int]) -> int:
+def resolve_word_width(word_width: Optional[int]) -> int:
+    """``None`` picks from the backend: the pair form (32) on TPU, whose
+    Mosaic backend has no 64-bit integer units, and uint64 elsewhere."""
     if word_width is None:
-        from ..compat import have_x64
-        return 64 if have_x64() else 32
+        return 32 if on_tpu() else 64
     if word_width not in (32, 64):
         raise PallascError(f"unknown word_width {word_width!r}; use 64 "
                            "(uint64 state, needs x64) or 32 (Mosaic-ready "
@@ -108,67 +95,62 @@ def _resolve_word_width(word_width: Optional[int]) -> int:
     return word_width
 
 
-def compile_pallas(prog: Program, vinfo=None, *, mode: Optional[str] = None,
+def compile_pallas(prog: Program, vinfo=None, *, mode: str = "pallas",
                    interpret: Optional[bool] = None,
                    word_width: Optional[int] = None):
     """Return (fn, map_names) — the in-graph calling convention.
 
     With ``word_width=64``: ``fn(ctx_vec, map_arrays) ->
     (ret, ctx_vec_out, map_arrays_out)``, ``ctx_vec`` uint64[n_fields],
-    maps uint64[max_entries, value_slots] — requires the x64 scope.
+    maps uint64[rows, cols] — requires the x64 scope.
 
     With ``word_width=32`` (the Mosaic-ready pair form): ``ctx_vec`` is
-    uint32[n_fields, 2], maps are uint32[max_entries, value_slots, 2]
-    (trailing axis = [lo, hi]), ``ret`` is uint32[2]; no x64 anywhere.
+    uint32[n_fields, 2], maps are uint32[rows, cols, 2] (trailing axis =
+    [lo, hi]), ``ret`` is uint32[2]; no x64 anywhere.
 
     ``vinfo`` reuses a prior :func:`verify_with_info` result (shared
     cfg / loop_bounds / max_steps / region info) — the runtime's load
-    path verifies once and hands the artifacts down.  ``mode=None``
-    auto-selects the Pallas kernel when available, the pure-JAX body
-    otherwise; ``interpret=None`` compiles through Mosaic on TPU and the
-    Pallas interpreter elsewhere (same lowering path either way);
-    ``word_width=None`` prefers 64 and falls back to 32 on builds whose
-    x64 scope does not work.
+    path verifies once and hands the artifacts down.  ``mode="jit"``
+    jits the bare lowering body instead of the kernel harness.
+    ``interpret=None`` compiles through Mosaic on TPU and the Pallas
+    interpreter elsewhere (same lowering path either way);
+    ``word_width=None`` follows :func:`resolve_word_width`.  The uint64
+    kernel is never handed to Mosaic.
     """
+    if mode not in ("pallas", "jit"):
+        raise PallascError(f"unknown pallasc mode {mode!r}; "
+                           "use 'pallas' or 'jit'")
+    word_width = resolve_word_width(word_width)
     try:
-        check_supported(prog)
+        check_supported(prog, word_width=word_width)
     except JaxcError as e:
         raise PallascError(
             f"policy '{prog.name}' cannot lower to the pallas tier: {e}"
         ) from e
     if vinfo is None:
         vinfo = verify_with_info(prog)
-    mode = _resolve_mode(mode)
-    word_width = _resolve_word_width(word_width)
-    lru = [d.name for d in prog.maps if d.kind == "lru_hash"]
-    if word_width == 32 and lru:
-        raise PallascError(
-            f"policy '{prog.name}' uses lru_hash map(s) "
-            f"{', '.join(repr(n) for n in lru)}; the 32-bit-pair tier does "
-            "not lower LRU recency/clock metadata.  Workarounds: declare "
-            "the map with kind=\"hash\" (the fixed-capacity open-addressing "
-            "table lowers in-graph on every tier, including pallas32 — you "
-            "lose eviction, inserts fail with E2BIG when full), keep "
-            "word_width=64 (x64 emulation), or run this policy on a host "
-            "tier (interp/jit/native), where lru_hash is fully supported")
     names = [d.name for d in prog.maps]
 
     if mode == "jit":
-        # pure-JAX fallback: the identical lowering body, no kernel harness
+        # the identical lowering body, no kernel harness
         if word_width == 32:
             return compile_jax32(prog, vinfo)
 
         def fn(ctx_vec, map_arrays: Dict[str, jnp.ndarray]):
-            with enable_x64(True):
+            with jax.enable_x64(True):
                 return _Lowerer(prog, vinfo,
                                 jnp.asarray(ctx_vec, jnp.uint64),
                                 map_arrays).run()
         return fn, names
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     if word_width == 32:
         return _build_pallas_fn32(prog, vinfo, interpret), names
+    if not interpret:
+        raise PallascError(
+            f"policy '{prog.name}': the uint64 kernel cannot lower through "
+            "Mosaic (no 64-bit integer units); use word_width=32")
     return _build_pallas_fn(prog, vinfo, interpret), names
 
 
@@ -218,7 +200,7 @@ def _build_pallas_fn(prog: Program, vinfo, interpret: bool) -> Callable:
     )
 
     def fn(ctx_vec, map_arrays: Dict[str, jnp.ndarray]):
-        with enable_x64(True):
+        with jax.enable_x64(True):
             args = [jnp.asarray(ctx_vec, jnp.uint64)]
             args += [jnp.asarray(map_arrays[n], jnp.uint64) for n in names]
             out = call(*args)
@@ -227,52 +209,55 @@ def _build_pallas_fn(prog: Program, vinfo, interpret: bool) -> Callable:
 
 
 def _build_pallas_fn32(prog: Program, vinfo, interpret: bool) -> Callable:
-    """The pair-form kernel: same harness shape as :func:`_build_pallas_fn`
-    but every operand is uint32 with a trailing [lo, hi] axis — the only
-    integer width inside the kernel is 32 bits, which is what hardware
-    Mosaic can lower natively."""
+    """The pair-form kernel: ctx, every map and the return value as flat
+    uint32 ``[lo, hi]`` word arrays in SMEM, the stack frames in SMEM
+    scratch.  The body is scalar control code — loads and stores at
+    static or clamped dynamic indices, 32-bit arithmetic, ``scf.for`` /
+    ``scf.while`` loops — which is what Mosaic lowers on the TPU's
+    scalar core.  Inputs are copied to the outputs once, then the
+    decision updates the outputs in place."""
     decls = list(prog.maps)
     names = [d.name for d in decls]
     n_maps = len(names)
     n_fields = prog.ctx_type.size // 8
+    shapes = [(*device_shape(d.kind, d.value_size, d.max_entries), 2)
+              for d in decls]
+    sizes = [n_fields * 2] + [math.prod(s) for s in shapes]
+
+    def copy(src, dst, n: int) -> None:
+        def body(i, c):
+            dst[i] = src[i]
+            return c
+        lax.fori_loop(0, n, body, 0)
 
     def kernel(*refs):
-        ctx_ref = refs[0]
-        map_refs = refs[1:1 + n_maps]
-        ret_ref = refs[1 + n_maps]
-        ctx_out_ref = refs[2 + n_maps]
-        out_map_refs = refs[3 + n_maps:]
-        ctx = ctx_ref[...]
-        maps = {n: r[...] for n, r in zip(names, map_refs)}
-        ret, ctx_out, maps_out = _Lowerer32(prog, vinfo, ctx, maps).run()
-        ret_ref[...] = jnp.stack([ret[0], ret[1]])
-        ctx_out_ref[...] = ctx_out
-        for n, r in zip(names, out_map_refs):
-            r[...] = maps_out[n]
+        ins, outs = refs[:1 + n_maps], refs[1 + n_maps:]
+        ret_ref, ctx_ref, map_refs = outs[0], outs[1], outs[2:2 + n_maps]
+        for src, dst, n in zip(ins, (ctx_ref, *map_refs), sizes):
+            copy(src, dst, n)
+        run_on_refs(prog, vinfo, ctx_ref, dict(zip(names, map_refs)),
+                    outs[-1], ret_ref)
 
-    vec_spec = pl.BlockSpec((n_fields, 2), lambda i: (0, 0))
-    map_shapes = [device_shape(d.kind, d.value_size, d.max_entries)
-                  for d in decls]
-    map_specs = [pl.BlockSpec((*s, 2), lambda i: (0, 0, 0))
-                 for s in map_shapes]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     call = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=[vec_spec] + map_specs,
-        out_specs=(pl.BlockSpec((2,), lambda i: (0,)), vec_spec,
-                   *map_specs),
+        in_specs=[smem] * (1 + n_maps),
+        out_specs=(smem,) * (2 + n_maps),
         out_shape=(jax.ShapeDtypeStruct((2,), jnp.uint32),
-                   jax.ShapeDtypeStruct((n_fields, 2), jnp.uint32),
-                   *[jax.ShapeDtypeStruct((*s, 2), jnp.uint32)
-                     for s in map_shapes]),
+                   *[jax.ShapeDtypeStruct((n,), jnp.uint32) for n in sizes]),
+        scratch_shapes=[pltpu.SMEM((n_frames(prog) * FRAME_WORDS,),
+                                   jnp.uint32)],
         interpret=interpret,
+        name=f"bpf_{prog.name}",
     )
 
     def fn(ctx_vec32, map_arrays32: Dict[str, jnp.ndarray]):
-        args = [jnp.asarray(ctx_vec32, jnp.uint32)]
-        args += [jnp.asarray(map_arrays32[n], jnp.uint32) for n in names]
+        args = [jnp.asarray(ctx_vec32, jnp.uint32).reshape(-1)]
+        args += [jnp.asarray(map_arrays32[n], jnp.uint32).reshape(-1)
+                 for n in names]
         out = call(*args)
-        return out[0], out[1], dict(zip(names, out[2:]))
+        return (out[0], out[1].reshape(n_fields, 2),
+                {n: o.reshape(s) for n, o, s in zip(names, out[2:], shapes)})
     return fn
 
 
@@ -362,7 +347,7 @@ class DeviceBridge:
 
     def __init__(self, prog: Program, resolved_maps: Dict[str, BpfMap],
                  vinfo=None, *, tier: str = "pallas",
-                 mode: Optional[str] = None, sync: str = "step",
+                 mode: str = "pallas", sync: str = "step",
                  n_shards: int = 1):
         if sync not in ("step", "deferred"):
             raise PallascError(f"unknown bridge sync policy {sync!r}; "
@@ -376,12 +361,8 @@ class DeviceBridge:
                 "use sync='deferred'")
         if vinfo is None:
             vinfo = verify_with_info(prog)
-        if tier == "pallas32":
-            ww = 32
-            fn, names = compile_pallas(prog, vinfo, mode=mode,
-                                       word_width=32)
-        elif tier == "pallas":
-            ww = _resolve_word_width(None)
+        if tier in ("pallas", "pallas32"):
+            ww = 32 if tier == "pallas32" else resolve_word_width(None)
             fn, names = compile_pallas(prog, vinfo, mode=mode,
                                        word_width=ww)
         elif tier == "jaxc":
@@ -413,9 +394,7 @@ class DeviceBridge:
                                      ct.offset_of("n_channels"))
             except KeyError:  # pragma: no cover — tuner ctx has them
                 pass
-        donate = jax.default_backend() in ("tpu", "gpu")
-        self._jfn = jax.jit(fn, donate_argnums=(1,)) if donate \
-            else jax.jit(fn)
+        self._jfn = self._compile(fn)
         self.n_shards = n_shards
         if n_shards > 1:
             from .shardmerge import MERGEABLE_KINDS
@@ -440,6 +419,34 @@ class DeviceBridge:
         self._cursors = [dict() for _ in range(n_shards)]
         self._lock = threading.Lock()
         self.stats = BridgeStats()
+
+    def _compile(self, fn):
+        """Compile the decision for its operand shapes at load, so a
+        program the backend cannot lower is rejected by the runtime's
+        load path instead of failing on the first decision."""
+        prog, ww = self._prog, self.word_width
+        n_fields = prog.ctx_type.size // 8
+        dt = jnp.uint32 if ww == 32 else jnp.uint64
+        lane = (2,) if ww == 32 else ()
+        ctx = jax.ShapeDtypeStruct((n_fields, *lane), dt)
+        maps = {d.name: jax.ShapeDtypeStruct(
+            (*device_shape(d.kind, d.value_size, d.max_entries), *lane), dt)
+            for d in prog.maps}
+        donate = jax.default_backend() in ("tpu", "gpu")
+        jfn = jax.jit(fn, donate_argnums=(1,)) if donate else jax.jit(fn)
+        try:
+            with maybe_x64(ww == 64):
+                return jfn.lower(ctx, maps).compile()
+        except Exception as e:
+            raise PallascError(
+                f"policy '{prog.name}' does not compile for the {self.tier} "
+                f"tier on {jax.default_backend()}: {type(e).__name__}: "
+                f"{str(e).splitlines()[0] if str(e) else ''}") from e
+
+    def compiled_text(self) -> str:
+        """The compiled decision program (``tpu_custom_call`` marks a
+        Mosaic kernel)."""
+        return self._jfn.as_text()
 
     # per-shard state, addressed through the currently-selected shard so
     # the call path reads identically in single- and multi-shard mode
@@ -690,7 +697,7 @@ class DeviceBridge:
 
 def compile_host(prog: Program, resolved_maps: Dict[str, BpfMap],
                  vinfo=None, *, tier: str = "pallas",
-                 mode: Optional[str] = None,
+                 mode: str = "pallas",
                  sync: str = "step", n_shards: int = 1) -> DeviceBridge:
     """Wrap an in-graph tier (pallas / pallas32 / jaxc) behind the host
     closure signature ``fn(ctx_buf) -> int`` the runtime invokes.

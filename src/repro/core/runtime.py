@@ -251,8 +251,8 @@ class PolicyRuntime:
       * ``"interp"`` — reference interpreter (differential ground truth)
       * ``"jaxc"``   — pure-JAX in-graph lowering behind the host bridge
       * ``"pallas"`` — single-Pallas-kernel in-graph lowering behind the
-        host bridge (zero host marginal cost once callers move the state
-        in-graph; see :mod:`repro.core.pallasc`)
+        host bridge at the backend's word width (``pallas32`` on TPU;
+        see :mod:`repro.core.pallasc`)
       * ``"pallas32"`` — the same kernel in the Mosaic-ready 32-bit-pair
         representation (every u64 as a (lo, hi) uint32 pair; no x64
         scope anywhere on the path — see :mod:`repro.core.lower32`)

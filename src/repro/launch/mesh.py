@@ -1,8 +1,9 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS (never module-level constants) so importing this
-module never touches jax device state — only dryrun.py (which sets
-XLA_FLAGS first) asks for the 256/512-device meshes.
+module never touches jax device state.  The training launcher spans the
+devices present (:func:`make_device_mesh`); only dryrun.py (which sets
+XLA_FLAGS first) asks for the 256/512-device production meshes.
 """
 
 from __future__ import annotations
@@ -16,6 +17,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes)
+
+
+def make_device_mesh(tp: int = 1):
+    """``("data", "model")`` mesh over every device this process sees,
+    ``tp`` devices per tensor-parallel group on the model axis."""
+    import numpy as np
+    devs = jax.devices()
+    if tp < 1 or len(devs) % tp:
+        raise ValueError(f"tp={tp} does not divide the {len(devs)} "
+                         f"device(s) present")
+    return jax.sharding.Mesh(np.array(devs).reshape(len(devs) // tp, tp),
+                             ("data", "model"))
 
 
 def mesh_axes(mesh, *, fsdp: bool = True,

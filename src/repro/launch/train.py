@@ -1,32 +1,32 @@
 """Training driver.
 
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
-      --steps 200 --seq 256 --batch 8 --smoke [--policy ring_mid_v2]
+      --steps 200 --seq 256 --batch 8 [--smoke] [--policy size_aware]
 
-On this CPU container use --smoke (reduced config, 1 device).  On a real
-pod, omit --smoke and launch one process per host with the production
-mesh (the step itself is identical — it's the same shard_map program the
-dry-run compiles for 256/512 chips).
+The ``("data", "model")`` mesh spans every device the process sees, with
+``--tp`` devices per tensor-parallel group on the model axis; parameters
+are FSDP-sharded over the data axis.  ``--smoke`` swaps in the reduced
+config of the same family (CPU-sized); ``--layers`` cuts the depth of
+the published config so it fits fewer chips, keeping its widths.
 """
 
 from __future__ import annotations
 
 import argparse
+from typing import Optional, Sequence
 
-import jax
 import numpy as np
-from jax.sharding import Mesh
 
 from ..configs import get_config, get_smoke_config
 from ..core.runtime import PolicyRuntime
 from ..collectives.dispatch import reset_dispatcher
 from ..data import DataConfig
-from ..models.layers import MeshAxes
 from ..train import AdamWConfig, Trainer, TrainerConfig, TrainStepConfig
-from .mesh import make_production_mesh, mesh_axes
+from .cache import enable_compile_cache
+from .mesh import make_device_mesh, mesh_axes
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--steps", type=int, default=100)
@@ -34,31 +34,39 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="decoder depth (0 keeps the config's own)")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="devices per tensor-parallel group")
     ap.add_argument("--policy", default="none")
     ap.add_argument("--bucketed", action="store_true")
+    ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    rt = PolicyRuntime()
+
+def build_trainer(args: argparse.Namespace,
+                  runtime: Optional[PolicyRuntime] = None) -> Trainer:
+    """The Trainer ``main`` runs: ``runtime`` (a fresh host-tier one by
+    default) backs the collective dispatcher, and ``--policy`` attaches
+    a shipped verified policy to it."""
+    rt = runtime if runtime is not None else PolicyRuntime()
     if args.policy != "none":
         import repro.policies as pol
-        rt.load(getattr(pol, args.policy).program)
-        print(f"loaded verified policy: {args.policy}")
+        rt.attach(getattr(pol, args.policy).program)
+        print(f"attached verified policy: {args.policy}")
     reset_dispatcher(runtime=rt)
 
-    if args.smoke:
-        cfg = get_smoke_config(args.arch)
-        mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                    ("data", "model"))
-        ax = MeshAxes(tp=1, dp=1, fsdp=False)
-    else:
-        cfg = get_config(args.arch).with_overrides(remat=True)
-        mesh = make_production_mesh()
-        ax = mesh_axes(mesh, fsdp=True)
+    cfg = get_smoke_config(args.arch) if args.smoke \
+        else get_config(args.arch).with_overrides(remat=True)
+    if args.layers:
+        cfg = cfg.with_overrides(n_layers=args.layers)
+    mesh = make_device_mesh(tp=args.tp)
+    ax = mesh_axes(mesh, fsdp=True)
 
     tcfg = TrainerConfig(
-        steps=args.steps, log_every=10,
+        steps=args.steps, log_every=args.log_every,
         ckpt_dir=args.ckpt_dir or f"/tmp/repro_ckpt_{args.arch}",
         ckpt_every=args.ckpt_every,
         data=DataConfig(seq_len=args.seq, global_batch=args.batch),
@@ -66,7 +74,13 @@ def main():
                              total_steps=args.steps, warmup_steps=max(
                                  args.steps // 20, 5),
                              bucketed_grad_sync=args.bucketed))
-    tr = Trainer(cfg, ax, mesh, tcfg)
+    return Trainer(cfg, ax, mesh, tcfg)
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    tr = build_trainer(args)
     if args.ckpt_every and tr.maybe_restore():
         print(f"restored from step {tr.step_idx}")
     log = tr.run()

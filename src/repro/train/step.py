@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..collectives.dispatch import dispatcher
 from ..core.context import AxisKind
 from ..models import loss_fn
@@ -135,6 +133,17 @@ def batch_specs(cfg: ModelConfig, ax: MeshAxes, *, replicate_batch=False):
     return s
 
 
+def opt_state_specs(param_specs):
+    """PartitionSpec tree of the AdamW state (``adamw_init``'s layout)."""
+    return {"m": param_specs, "v": param_specs, "step": P()}
+
+
+def named_shardings(mesh: Mesh, specs):
+    """The ``NamedSharding`` tree of a PartitionSpec tree on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda v: isinstance(v, P))
+
+
 def make_train_step(cfg: ModelConfig, ax: MeshAxes, mesh: Mesh,
                     param_specs, step_cfg: TrainStepConfig
                     ) -> Tuple[Callable, Callable]:
@@ -143,7 +152,7 @@ def make_train_step(cfg: ModelConfig, ax: MeshAxes, mesh: Mesh,
     step(params, opt_state, batch) -> (params, opt_state, metrics)
     """
     bspecs = batch_specs(cfg, ax)
-    opt_specs = {"m": param_specs, "v": param_specs, "step": P()}
+    opt_specs = opt_state_specs(param_specs)
     metric_specs = {"loss": P(), "grad_norm": P(), "lr": P()}
 
     def local_step(params, opt_state, batch):
@@ -163,23 +172,17 @@ def make_train_step(cfg: ModelConfig, ax: MeshAxes, mesh: Mesh,
         metrics["loss"] = loss
         return params, opt_state, metrics
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(param_specs, opt_specs, bspecs),
         out_specs=(param_specs, opt_specs, metric_specs),
         check_vma=False)
 
-    def shardings(tree):
-        return jax.tree.map(
-            lambda s: NamedSharding(mesh, s), tree,
-            is_leaf=lambda v: isinstance(v, P))
-
     jitted = jax.jit(
         sm,
-        in_shardings=(shardings(param_specs), shardings(opt_specs),
-                      shardings(bspecs)),
-        out_shardings=(shardings(param_specs), shardings(opt_specs),
-                       shardings(metric_specs)),
+        in_shardings=named_shardings(mesh, (param_specs, opt_specs, bspecs)),
+        out_shardings=named_shardings(mesh, (param_specs, opt_specs,
+                                             metric_specs)),
         donate_argnums=(0, 1))
     return jitted, opt_specs
 
@@ -203,7 +206,7 @@ def make_serve_step(cfg: ModelConfig, ax: MeshAxes, mesh: Mesh,
         def local_prefill(params, batch):
             return prefill(params, batch, cfg, ax)
 
-        sm = shard_map(local_prefill, mesh=mesh,
+        sm = jax.shard_map(local_prefill, mesh=mesh,
                            in_specs=(param_specs, bspecs),
                            out_specs=out_spec, check_vma=False)
         return jax.jit(sm)
@@ -213,7 +216,7 @@ def make_serve_step(cfg: ModelConfig, ax: MeshAxes, mesh: Mesh,
     def local_decode(params, token, caches, pos):
         return decode_step(params, token, caches, pos, cfg, ax)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local_decode, mesh=mesh,
         in_specs=(param_specs, tok_spec, cache_specs, P(dp_axes)),
         out_specs=(tok_spec, cache_specs), check_vma=False)

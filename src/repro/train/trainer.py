@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from ..collectives.dispatch import dispatcher
 from ..core.context import CollType
@@ -26,7 +26,8 @@ from ..models.config import ModelConfig
 from ..models.layers import MeshAxes
 from .checkpoint import latest_step, load_checkpoint, save_checkpoint
 from .optimizer import adamw_init
-from .step import TrainStepConfig, make_train_step
+from .step import (TrainStepConfig, make_train_step, named_shardings,
+                   opt_state_specs)
 
 
 @dataclasses.dataclass
@@ -49,12 +50,27 @@ class Trainer:
         self.tcfg = tcfg
         self.metrics_log: List[Dict[str, float]] = []
 
-        self.params, self.param_specs = init_params(
-            jax.random.PRNGKey(tcfg.seed), cfg, ax)
-        self.opt_state = adamw_init(self.params)
+        self._init_state()
         self._build_step()
         self._policy_epoch = dispatcher().epoch
         self.step_idx = 0
+
+    def _init_state(self):
+        """Parameters and AdamW state, created already sharded over the
+        mesh.  Built eagerly, every array would land whole on the first
+        device, which cannot hold a full-size model's state."""
+        specs = {}
+
+        def init(key):
+            params, specs["params"] = init_params(key, self.cfg, self.ax)
+            return params, adamw_init(params)
+
+        key = jax.random.PRNGKey(self.tcfg.seed)
+        jax.eval_shape(init, key)               # the specs, no arrays
+        self.param_specs = specs["params"]
+        out = named_shardings(self.mesh, (self.param_specs,
+                                          opt_state_specs(self.param_specs)))
+        self.params, self.opt_state = jax.jit(init, out_shardings=out)(key)
 
     def _build_step(self):
         self._step_fn, self.opt_specs = make_train_step(

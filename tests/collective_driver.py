@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.core.context import Algo, Proto
 from repro.collectives import algorithms as alg
@@ -40,7 +39,7 @@ def main():
     failures = 0
 
     def run_spmd(fn, x):
-        m = shard_map(fn, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+        m = jax.shard_map(fn, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
         return jax.jit(m)(x)
 
     # ---- allreduce variants vs psum ------------------------------------

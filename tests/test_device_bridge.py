@@ -22,12 +22,6 @@ from repro.policies.loops import histogram_bucket_tuner, latency_argmin_tuner
 CTX_KW = dict(msg_size=8 << 20, comm_id=0, n_ranks=8, max_channels=32)
 
 
-def _x64_or_skip():
-    from repro.compat import have_x64
-    if not have_x64():
-        pytest.skip("jax build lacks a working enable_x64")
-
-
 def _seed_argmin(rt):
     m = rt.maps.get("config_lat_map")
     for k in range(0, m.max_entries, 5):
@@ -36,8 +30,6 @@ def _seed_argmin(rt):
 
 @pytest.mark.parametrize("tier", ["pallas", "pallas32"])
 def test_warm_repeat_calls_zero_uploads(tier):
-    if tier == "pallas":
-        _x64_or_skip()
     rt = PolicyRuntime(tier=tier)
     lp = rt.load(latency_argmin_tuner.program)
     _seed_argmin(rt)
@@ -54,8 +46,6 @@ def test_warm_repeat_calls_zero_uploads(tier):
 
 @pytest.mark.parametrize("tier", ["pallas", "pallas32"])
 def test_host_mutation_between_calls_is_picked_up(tier):
-    if tier == "pallas":
-        _x64_or_skip()
     rt = PolicyRuntime(tier=tier)
     rt.load(latency_argmin_tuner.program)
     bridge = rt.attached("tuner").fn
@@ -79,7 +69,6 @@ def test_host_mutation_between_calls_is_picked_up(tier):
 
 
 def test_step_sync_written_state_visible_immediately():
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas")        # default sync="step"
     rt.load(histogram_bucket_tuner.program)
     m = rt.maps.get("size_hist_map")
@@ -90,8 +79,6 @@ def test_step_sync_written_state_visible_immediately():
 
 @pytest.mark.parametrize("tier", ["pallas", "pallas32"])
 def test_deferred_sync_state_lands_at_flush(tier):
-    if tier == "pallas":
-        _x64_or_skip()
     rt = PolicyRuntime(tier=tier, bridge_sync="deferred")
     lp = rt.load(histogram_bucket_tuner.program)
     bridge = lp.fn
@@ -107,7 +94,6 @@ def test_deferred_sync_state_lands_at_flush(tier):
 
 
 def test_deferred_sync_flushes_on_detach():
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas", bridge_sync="deferred")
     lp = rt.load(histogram_bucket_tuner.program)
     m = rt.maps.get("size_hist_map")
@@ -122,7 +108,6 @@ def test_deferred_sync_flushes_on_hot_reload():
     """reload() (legacy single-slot swap) is a T3 boundary: the outgoing
     kernel's accumulated state must land in the host maps the incoming
     program starts from."""
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas", bridge_sync="deferred")
     old = rt.load(histogram_bucket_tuner.program)
     m = rt.maps.get("size_hist_map")
@@ -139,7 +124,6 @@ def test_deferred_sync_flushes_on_hot_reload():
 
 
 def test_deferred_sync_flushes_on_link_replace():
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas", bridge_sync="deferred")
     link = rt.attach(histogram_bucket_tuner.program)
     m = rt.maps.get("size_hist_map")
@@ -150,7 +134,6 @@ def test_deferred_sync_flushes_on_link_replace():
 
 
 def test_deferred_sync_flushes_on_bundle_reload():
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas", bridge_sync="deferred")
     rt.load_bundle([histogram_bucket_tuner.program])
     m = rt.maps.get("size_hist_map")
@@ -161,7 +144,6 @@ def test_deferred_sync_flushes_on_bundle_reload():
 
 
 def test_invalidate_forces_reupload():
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas")
     lp = rt.load(latency_argmin_tuner.program)
     bridge = lp.fn
@@ -177,7 +159,6 @@ def test_flush_never_writes_back_lookup_only_maps():
     """flush() (and therefore every T3 boundary) must not revert host
     mutations to maps the kernel can only read — the kernel cannot have
     changed them, so their stale device copy must never win."""
-    _x64_or_skip()
     rt = PolicyRuntime(tier="pallas")
     lp = rt.load(latency_argmin_tuner.program)
     _seed_argmin(rt)

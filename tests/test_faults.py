@@ -13,7 +13,6 @@ import pytest
 
 from repro.collectives.dispatch import (CollectiveDispatcher,
                                         DispatchConfig)
-from repro.compat import have_x64
 from repro.core import (BreakerConfig, FaultInjector, InjectedFault,
                         MapRegistry, PolicyRuntime, make_ctx, map_decl,
                         policy)
@@ -24,8 +23,7 @@ from repro.policies import table1 as T
 from repro.core.cc import have_cc
 
 MiB = 1 << 20
-ALL_TIERS = ["interp", "jit", "jaxc", "pallas32"] + \
-    (["pallas"] if have_x64() else []) + \
+ALL_TIERS = ["interp", "jit", "jaxc", "pallas32", "pallas"] + \
     (["native"] if have_cc() else [])
 
 

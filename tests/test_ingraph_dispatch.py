@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
 from repro.collectives.ingraph import InGraphSelector
 from repro.core import map_decl, policy
 from repro.core.context import Algo
@@ -59,9 +58,9 @@ def test_decisions_adapt_without_retrace():
         return algo, state
 
     # fast regime -> default(0); slow regime -> tree(2); recovery -> default
-    # (x64 scope wraps the jit calls: 0.4.x boundary-canonicalization rule)
+    # (x64 scope wraps the jit calls so uint64 inputs are not canonicalized)
     seen = []
-    with enable_x64(True):
+    with jax.enable_x64(True):
         for lat in [1_000] * 4 + [5_000_000] * 6 + [1_000] * 8:
             algo, state = step(state, jnp.uint32(lat))
             seen.append(int(algo))
@@ -81,10 +80,8 @@ def test_ingraph_allreduce_correct_on_8_devices():
 import jax, jax.numpy as jnp, numpy as np, sys
 sys.path.insert(0, %r)
 from jax import lax
-from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from test_ingraph_dispatch import adaptive_ingraph
-from repro.compat import enable_x64
 from repro.collectives.ingraph import InGraphSelector
 
 sel = InGraphSelector(adaptive_ingraph.program)
@@ -96,13 +93,13 @@ def f(v, state, lat):
     y, algo, state = sel.all_reduce(v, "x", state, latency_ns=lat)
     return y, algo, state
 
-sm = jax.jit(shard_map(f, mesh=mesh,
+sm = jax.jit(jax.shard_map(f, mesh=mesh,
                        in_specs=(P("x"), P(), P()), out_specs=(P("x"), P(), P()),
                        check_vma=False))
-want = jax.jit(shard_map(lambda v: lax.psum(v, "x"), mesh=mesh,
+want = jax.jit(jax.shard_map(lambda v: lax.psum(v, "x"), mesh=mesh,
                          in_specs=P("x"), out_specs=P("x")))(x)
 algos = []
-with enable_x64(True):
+with jax.enable_x64(True):
     for lat in [1000]*3 + [5_000_000]*4:
         y, algo, state = sm(x, state, jnp.uint32(lat))
         assert np.allclose(np.asarray(y), np.asarray(want), atol=1e-4), "wrong result"

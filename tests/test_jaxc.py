@@ -9,7 +9,6 @@ import jax
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
 from repro.core import PolicyRuntime, make_ctx
 from repro.core.context import POLICY_CONTEXT
 from repro.core.jaxc import (JaxcError, compile_jax, ctx_to_vec,
@@ -39,10 +38,9 @@ def _run_both(pol, ctx_kwargs, seed_maps=None):
     jctx = make_ctx("tuner", **ctx_kwargs)
     vec = ctx_to_vec(jctx.buf)
     arrays = {n: map_to_array(rt2_map(pol, n, seed_maps)) for n in names}
-    # hold the x64 scope across the jit boundary: on the 0.4.x line a
-    # context manager *inside* the trace cannot re-widen inputs that the
-    # outer bind already canonicalized to 32-bit
-    with enable_x64(True):
+    # hold the x64 scope across the jit boundary: a scope *inside* the
+    # trace cannot re-widen inputs the outer bind canonicalized to 32-bit
+    with jax.enable_x64(True):
         jret, vec_out, arrays_out = jax.jit(fn)(vec, arrays)
         return hctx, np.asarray(vec_out), int(hret), int(jret)
 
@@ -101,8 +99,8 @@ def test_adaptive_policy_state_evolves_in_graph():
     m.update_u64(5, 1, slot=2)
 
     arrays = {"adapt_map": map_to_array(m)}
-    # x64 scope wraps the jit calls (0.4.x boundary-canonicalization rule)
-    with enable_x64(True):
+    # x64 scope wraps the jit calls so uint64 inputs are not canonicalized
+    with jax.enable_x64(True):
         for step in range(3):
             ctx = make_ctx("tuner", comm_id=5)
             vec = ctx_to_vec(ctx.buf)
@@ -148,8 +146,8 @@ def test_jaxc_composes_with_outer_jit_32bit():
         return x * nch, vec_out
 
     vec = ctx_to_vec(make_ctx("tuner", msg_size=MiB).buf)
-    # the x64 scope wraps the outer jit (0.4.x requirement); the outer
-    # program still computes in explicit 32-bit dtypes throughout
-    with enable_x64(True):
+    # the x64 scope wraps the outer jit so the uint64 ctx stays 64-bit;
+    # the outer program still computes in explicit 32-bit dtypes throughout
+    with jax.enable_x64(True):
         y, _ = jax.jit(step)(jnp.uint32(3), vec)
     assert int(y) == 3

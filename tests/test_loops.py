@@ -3,12 +3,13 @@ loop bytecode, VM fuel, JIT v1/v2 loop codegen, jaxc fori_loop lowering.
 
 The differential property test generates random verified bounded-loop
 programs (seeded, no hypothesis dependency) and asserts identical results
-and ctx/map state across interpreter, JIT v1, JIT v2, and jaxc (the jaxc
-leg skips cleanly when the jax build lacks a working enable_x64).
+and ctx/map state across interpreter, JIT v1, JIT v2, and jaxc (under
+``jax.enable_x64``).
 """
 
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,6 +17,7 @@ from repro.core import PolicyRuntime, assemble, make_ctx, map_decl, verify
 from repro.core.cfg import CFG
 from repro.core.context import POLICY_CONTEXT
 from repro.core.frontend import CompileError, _MAX_UNROLL, compile_policy
+from repro.core.jaxc import compile_jax, ctx_to_vec, map_to_array
 from repro.core.jit import compile_program
 from repro.core.verifier import (LOOP_FUEL_CAP, VerifierError,
                                  verify_with_info)
@@ -616,16 +618,6 @@ def _seed_maps(rt):
             m.update_u64(k, 100 + 17 * k, slot=0)
 
 
-def _jaxc_or_skip():
-    from repro.compat import have_x64
-    if not have_x64():
-        pytest.skip("jax build lacks a working enable_x64")
-    import jax
-    from repro.compat import enable_x64
-    from repro.core.jaxc import compile_jax, ctx_to_vec, map_to_array
-    return jax, enable_x64, compile_jax, ctx_to_vec, map_to_array
-
-
 @pytest.mark.parametrize("pol", LOOP_POLICIES,
                          ids=lambda p: p.program.name)
 def test_loop_policy_identical_across_tiers(pol):
@@ -660,14 +652,13 @@ def test_loop_policy_identical_across_tiers(pol):
                  for d in prog.maps}
         assert (ret, bytes(ctx.buf), state) == results["interp"]
 
-    jax, enable_x64, compile_jax, ctx_to_vec, map_to_array = _jaxc_or_skip()
     rt = PolicyRuntime(use_interpreter=True)
     rt.load(prog)
     _seed_maps(rt)
     arrays = {d.name: map_to_array(rt.maps.get(d.name)) for d in prog.maps}
     fn, names = compile_jax(prog)
     ctx = make_ctx("tuner", **ctx_kw)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         jret, vec_out, arrays_out = jax.jit(fn)(ctx_to_vec(ctx.buf), arrays)
     want_ret, want_buf, want_state = results["interp"]
     assert int(jret) == want_ret
@@ -761,13 +752,12 @@ def test_random_bounded_loops_identical_across_tiers(seed):
 
 @pytest.mark.parametrize("seed", range(6))
 def test_random_bounded_loops_match_jaxc(seed):
-    jax, enable_x64, compile_jax, ctx_to_vec, _ = _jaxc_or_skip()
     rng = random.Random(0xFACE + seed)
     prog = _random_loop_program(rng)
     buf = make_ctx("tuner", msg_size=1 << 20).buf
     want = VM(prog.insns, {}).run(bytearray(buf))
     fn, _names = compile_jax(prog)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         jret, _, _ = jax.jit(fn)(ctx_to_vec(bytearray(buf)), {})
     assert int(jret) == want
 
@@ -823,7 +813,6 @@ def test_random_bounded_loops_match_pallas(seed):
     """interp == pallas on >= 20 seeded random loop programs, map state
     compared after each run (the pallas analogue of the jaxc leg, with
     map writebacks in the loop body)."""
-    jax, enable_x64, _, ctx_to_vec, map_to_array = _jaxc_or_skip()
     from repro.core.maps import MapRegistry
     from repro.core.pallasc import compile_pallas
 
@@ -842,7 +831,7 @@ def test_random_bounded_loops_match_pallas(seed):
     want_state = [m.lookup_u64(k) for k in range(8)]
 
     fn, _names = compile_pallas(prog, vinfo, word_width=64)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         ret, _, arrs = jax.jit(fn)(ctx_to_vec(bytearray(buf)), arrays)
     assert int(ret) == want
     got = [int(x) for x in np.asarray(arrs["rand_loop_map"])[:, 0]]
@@ -986,7 +975,6 @@ def test_random_real_loop_with_call_and_hash_all_tiers(seed):
     seeded real-loop programs calling a subroutine and hash-RMWing per
     iteration: return value, ctx writeback, and decoded hash state
     (both value slots, present and absent keys) bit-identical."""
-    from repro.compat import have_x64
     from repro.core.cc import compile_native, have_cc
     from repro.core.pallasc import compile_host
 
@@ -1017,14 +1005,11 @@ def test_random_real_loop_with_call_and_hash_all_tiers(seed):
         "v1": lambda p, m, v: compile_program(p, m, codegen="v1"),
         "v2": lambda p, m, v: compile_program(p, m, info=v),
         "pallas32": lambda p, m, v: compile_host(p, m, v, tier="pallas32"),
+        "jaxc": lambda p, m, v: compile_host(p, m, v, tier="jaxc"),
+        "pallas": lambda p, m, v: compile_host(p, m, v, tier="pallas"),
     }
     if have_cc():
         builders["native"] = compile_native
-    if have_x64():
-        builders["jaxc"] = lambda p, m, v: compile_host(p, m, v,
-                                                        tier="jaxc")
-        builders["pallas"] = lambda p, m, v: compile_host(p, m, v,
-                                                          tier="pallas")
     for tier, build in builders.items():
         maps_t = mk_maps()
         fn = build(prog, maps_t, vinfo)
@@ -1066,14 +1051,13 @@ def test_negative_init_do_while_gets_real_trip_bound():
 
 
 def test_negative_init_do_while_identical_across_tiers():
-    jax, enable_x64, compile_jax, ctx_to_vec, _ = _jaxc_or_skip()
     prog = _tuner(WRAP_INIT_DO_WHILE)
     buf = make_ctx("tuner").buf
     want = VM(prog.insns, {}).run(bytearray(buf))
     f2 = compile_program(prog, {})
     assert f2(bytearray(buf)) == want
     fn, _ = compile_jax(prog)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         jret, _, _ = jax.jit(fn)(ctx_to_vec(bytearray(buf)), {})
     assert int(jret) == want, \
         "jaxc ran a different trip count than the interpreter"

@@ -26,7 +26,6 @@ import threading
 
 import pytest
 
-from repro.compat import have_x64
 from repro.core.context import make_ctx
 from repro.core.frontend import map_decl, policy
 from repro.core.maps import (LruHashMap, MapError, PerDeviceArrayMap,
@@ -295,8 +294,6 @@ def test_rb_writer_tier_differential(tier):
     drop-on-full rejections, FIFO drain, then rows reused for 4 more
     accepted writes after the drain — including the in-graph tiers'
     device write cursor drained at flush()."""
-    if tier in ("jaxc", "pallas") and not have_x64():
-        pytest.skip("uint64 in-graph tiers need x64")
     want = _rb_ground_truth()
     got = _drive_rb_writer(PolicyRuntime(tier=tier))
     assert got == want
@@ -367,9 +364,7 @@ def test_profiler_suite_tier_differential():
     want = run(use_interpreter=True)
     assert sum(want[3]) == 50                 # every event bucketed
     assert len(want[1]) > 0                   # stragglers fired
-    tiers = [dict()]
-    if have_x64():
-        tiers += [dict(tier="jaxc"), dict(tier="pallas")]
+    tiers = [dict(), dict(tier="jaxc"), dict(tier="pallas")]
     for kw in tiers:
         assert run(**kw) == want, kw
 
@@ -476,7 +471,6 @@ def test_runtime_health_observability_sections():
     assert "recorder" not in rt.health()["observability"]
 
 
-@pytest.mark.skipif(not have_x64(), reason="bridge tiers need x64")
 def test_runtime_health_aggregates_bridge_stats():
     rt = PolicyRuntime(tier="pallas")
     rt.attach(rb_writer.program)

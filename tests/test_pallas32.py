@@ -455,7 +455,7 @@ def test_lru_hash_rejected_with_concrete_workarounds():
     msg = str(ei.value)
     assert "lru_hash" in msg and "'ema_map'" in msg
     assert 'kind="hash"' in msg              # workaround 1: plain hash
-    assert "word_width=64" in msg            # workaround 2: x64 emulation
+    assert "word_width=64" in msg            # workaround 2: off the TPU
     assert "host tier" in msg                # workaround 3: interp/jit/native
     # the eligibility probe agrees (it drives the BENCH audit + CI gate)
     with pytest.raises(JaxcError, match="lru_hash"):

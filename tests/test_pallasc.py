@@ -1,29 +1,20 @@
 """The pallas in-graph tier: single-kernel lowering of verified policies.
 
 Covers loop lowering (pallas == interpreter incl. map state), the
-pure-JAX ``mode="jit"`` fallback on non-TPU backends, verifier-artifact
+explicit pure-JAX ``mode="jit"`` body, the backend-chosen word width,
+load-time compile rejection, verifier-artifact
 reuse (one static pass per load, never two), the runtime's
 ``tier="pallas"`` selection, and the dispatcher's in-graph routing with
 zero retraces across decisions.
 """
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import PolicyRuntime, assemble, make_ctx, map_decl
+from repro.core import PolicyRuntime, assemble, make_ctx, map_decl, pallasc
 from repro.core.vm import VM
 from repro.policies.loops import LOOP_POLICIES, latency_argmin_tuner
-
-
-def _x64_or_skip():
-    from repro.compat import have_x64
-    if not have_x64():
-        pytest.skip("jax build lacks a working enable_x64")
-    import jax
-
-    from repro.compat import enable_x64
-    from repro.core import pallasc
-    return jax, enable_x64, pallasc
 
 
 def _seed_maps(rt):
@@ -55,7 +46,6 @@ CTX_KW = dict(msg_size=8 << 20, comm_id=2, n_ranks=8, max_channels=32)
 @pytest.mark.parametrize("pol", LOOP_POLICIES, ids=lambda p: p.program.name)
 @pytest.mark.parametrize("mode", ["pallas", "jit"])
 def test_loop_policy_matches_interpreter(pol, mode):
-    jax, enable_x64, pallasc = _x64_or_skip()
     from repro.core.jaxc import ctx_to_vec, map_to_array
 
     prog = pol.program
@@ -67,7 +57,7 @@ def test_loop_policy_matches_interpreter(pol, mode):
     arrays = {d.name: map_to_array(rt.maps.get(d.name)) for d in prog.maps}
     fn, names = pallasc.compile_pallas(prog, mode=mode)
     ctx = make_ctx("tuner", **CTX_KW)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         ret, vec_out, arrays_out = jax.jit(fn)(ctx_to_vec(ctx.buf), arrays)
     assert int(ret) == want_ret
     assert np.asarray(vec_out).astype("<u8").tobytes() == want_buf
@@ -77,9 +67,8 @@ def test_loop_policy_matches_interpreter(pol, mode):
 
 
 def test_jit_fallback_equals_pallas_kernel():
-    """The pure-JAX fallback and the pallas_call kernel are the same
-    lowering — byte-identical outputs on the same inputs."""
-    jax, enable_x64, pallasc = _x64_or_skip()
+    """The pure-JAX body (``mode="jit"``) and the pallas_call kernel are
+    the same lowering — byte-identical outputs on the same inputs."""
     from repro.core.jaxc import ctx_to_vec, map_to_array
 
     prog = latency_argmin_tuner.program
@@ -90,7 +79,7 @@ def test_jit_fallback_equals_pallas_kernel():
     outs = {}
     for mode in ("pallas", "jit"):
         fn, names = pallasc.compile_pallas(prog, mode=mode)
-        with enable_x64(True):
+        with jax.enable_x64(True):
             ret, vec, arrs = jax.jit(fn)(
                 ctx_to_vec(make_ctx("tuner", **CTX_KW).buf), arrays)
         outs[mode] = (int(ret), np.asarray(vec).tobytes(),
@@ -99,9 +88,60 @@ def test_jit_fallback_equals_pallas_kernel():
 
 
 def test_unknown_mode_rejected():
-    _, _, pallasc = _x64_or_skip()
     with pytest.raises(pallasc.PallascError, match="mode"):
         pallasc.compile_pallas(latency_argmin_tuner.program, mode="mosaic")
+
+
+def test_default_mode_is_the_kernel_never_a_silent_fallback():
+    """``mode`` defaults to the Pallas kernel; the pure-JAX body runs
+    only when asked for by name."""
+    import inspect
+    sig = inspect.signature(pallasc.compile_pallas)
+    assert sig.parameters["mode"].default == "pallas"
+    assert not hasattr(pallasc, "HAVE_PALLAS")
+
+
+@pytest.mark.parametrize("on_tpu,want", [(True, 32), (False, 64)])
+def test_word_width_follows_backend(monkeypatch, on_tpu, want):
+    """On TPU ``word_width=None`` and ``tier="pallas"`` take the pair
+    form — Mosaic has no 64-bit integer units; elsewhere uint64."""
+    from repro.collectives.ingraph import InGraphSelector
+    monkeypatch.setattr(pallasc, "on_tpu", lambda: on_tpu)
+    assert pallasc.resolve_word_width(None) == want
+    sel = InGraphSelector(latency_argmin_tuner.program, tier="pallas")
+    assert sel.word_width == want
+    assert pallasc.resolve_word_width(32) == 32
+    with pytest.raises(pallasc.PallascError, match="word_width"):
+        pallasc.resolve_word_width(16)
+
+
+def test_uint64_kernel_never_handed_to_mosaic():
+    with pytest.raises(pallasc.PallascError, match="Mosaic"):
+        pallasc.compile_pallas(latency_argmin_tuner.program, word_width=64,
+                               interpret=False)
+
+
+def test_bridge_compile_failure_rejected_at_load(monkeypatch):
+    """A program the backend cannot compile is a load-time rejection
+    (counted in ``compile_failures``, old chain untouched) — never a
+    first-decision failure the guarded dispatcher would hide."""
+    real = pallasc.compile_pallas
+
+    def mosaic_only(prog, vinfo=None, **kw):
+        return real(prog, vinfo, **dict(kw, interpret=False))
+    monkeypatch.setattr(pallasc, "compile_pallas", mosaic_only)
+    rt = PolicyRuntime(tier="pallas32")
+    with pytest.raises(pallasc.PallascError, match="does not compile"):
+        rt.attach(latency_argmin_tuner.program)
+    assert rt.stats.compile_failures == 1
+    assert rt.attached("tuner") is None
+
+
+def test_bridge_exposes_compiled_text():
+    rt = PolicyRuntime(tier="pallas32")
+    lp = rt.load(latency_argmin_tuner.program)
+    text = lp.fn.compiled_text()
+    assert "HloModule" in text or "module" in text
 
 
 def test_hash_map_policy_runs_in_kernel():
@@ -110,7 +150,6 @@ def test_hash_map_policy_runs_in_kernel():
     device-resident and matches the interpreter — return value, ctx
     writeback, and decoded per-key state (insert on first sight, then
     in-place RMW on the warm key)."""
-    _, _, pallasc = _x64_or_skip()
     from repro.core.maps import MapRegistry
     from repro.core.verifier import verify_with_info
     from repro.policies import table1 as T
@@ -151,7 +190,6 @@ def test_hash_map_policy_runs_in_kernel():
 def test_compile_reuses_provided_verifier_artifacts(monkeypatch):
     """With a vinfo handed in, compile_pallas must not re-verify — the
     runtime's load path pays for exactly one static pass."""
-    jax, enable_x64, pallasc = _x64_or_skip()
     from repro.core import verifier as verifier_mod
     from repro.core.jaxc import ctx_to_vec
 
@@ -171,13 +209,12 @@ def test_compile_reuses_provided_verifier_artifacts(monkeypatch):
         raise AssertionError("re-verified despite provided artifacts")
     monkeypatch.setattr(pallasc, "verify_with_info", boom)
     fn, _ = pallasc.compile_pallas(prog, vinfo)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         ret, _, _ = jax.jit(fn)(ctx_to_vec(make_ctx("tuner").buf), {})
     assert int(ret) == 100
 
 
 def test_runtime_load_verifies_exactly_once(monkeypatch):
-    jax, enable_x64, pallasc = _x64_or_skip()
     import repro.core.runtime as runtime_mod
     calls = []
     real = runtime_mod.verify_with_info
@@ -202,7 +239,6 @@ def test_runtime_rejects_unknown_tier():
 
 @pytest.mark.parametrize("tier", ["jaxc", "pallas"])
 def test_runtime_tier_matches_interpreter(tier):
-    _x64_or_skip()
     prog = latency_argmin_tuner.program
     want_ret, want_buf, want_state = _interp_results(prog, CTX_KW)
     rt = PolicyRuntime(tier=tier)
@@ -220,7 +256,6 @@ def test_runtime_tier_matches_interpreter(tier):
 def test_runtime_pallas_tier_writes_map_state_back():
     """Closed loop through the host bridge: a map-writing policy's state
     lands back in the host maps (the cross-plugin source of truth)."""
-    _x64_or_skip()
     from repro.policies.loops import histogram_bucket_tuner
     rt = PolicyRuntime(tier="pallas")
     rt.load(histogram_bucket_tuner.program)
@@ -233,7 +268,6 @@ def test_runtime_pallas_tier_writes_map_state_back():
 def test_runtime_pallas_hot_reload_keeps_t3():
     """Verify-then-swap semantics hold on the pallas tier too: a rejected
     replacement leaves the old kernel attached."""
-    _x64_or_skip()
     from repro.policies.unsafe import unbounded_loop
     rt = PolicyRuntime(tier="pallas")
     rt.load(latency_argmin_tuner.program)
@@ -250,7 +284,6 @@ def test_runtime_pallas_hot_reload_keeps_t3():
 # ---------------------------------------------------------------------------
 
 def test_ingraph_selector_pallas_zero_retraces():
-    jax, enable_x64, _ = _x64_or_skip()
     import jax.numpy as jnp
 
     from repro.collectives.ingraph import InGraphSelector
@@ -268,7 +301,7 @@ def test_ingraph_selector_pallas_zero_retraces():
         return algo, state
 
     seen = []
-    with enable_x64(True):
+    with jax.enable_x64(True):
         for lat in [1_000] * 4 + [5_000_000] * 6 + [1_000] * 8:
             algo, state = step(state, jnp.uint32(lat))
             seen.append(int(algo))
@@ -278,7 +311,6 @@ def test_ingraph_selector_pallas_zero_retraces():
 
 
 def test_dispatcher_routes_ingraph_with_live_state():
-    jax, enable_x64, _ = _x64_or_skip()
     from repro.collectives.dispatch import CollectiveDispatcher
 
     rt = PolicyRuntime()
@@ -291,14 +323,13 @@ def test_dispatcher_routes_ingraph_with_live_state():
     assert sel.tier == "pallas"
     # host-accumulated telemetry moved in-graph with the policy
     assert int(np.asarray(state["config_lat_map"])[11, 0]) == 50
-    with enable_x64(True):
+    with jax.enable_x64(True):
         algo, ch, state = jax.jit(
             lambda s: sel.decide(s, coll=0, msg_bytes=1 << 20, n=8))(state)
     assert int(ch) == 12                   # argmin config + 1
 
 
 def test_dispatcher_ingraph_requires_attached_tuner():
-    _x64_or_skip()
     from repro.collectives.dispatch import CollectiveDispatcher
     disp = CollectiveDispatcher(runtime=PolicyRuntime())
     with pytest.raises(RuntimeError, match="no tuner policy attached"):
@@ -314,7 +345,6 @@ accum_map = map_decl("pallas_accum", kind="array", value_size=8,
 
 
 def test_loop_with_map_writeback_matches_vm():
-    jax, enable_x64, pallasc = _x64_or_skip()
     from repro.core.jaxc import ctx_to_vec, map_to_array
     from repro.core.maps import MapRegistry
 
@@ -350,7 +380,7 @@ def test_loop_with_map_writeback_matches_vm():
     m2 = reg2.create("pallas_accum", "array", value_size=8, max_entries=4)
     m2.update_u64(1, 7)
     fn, _ = pallasc.compile_pallas(prog)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         ret, _, arrs = jax.jit(fn)(ctx_to_vec(make_ctx("tuner").buf),
                                    {"pallas_accum": map_to_array(m2)})
     assert int(ret) == want

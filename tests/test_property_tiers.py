@@ -106,9 +106,11 @@ def _seeded_ctx_kwargs(rng: random.Random) -> dict:
 def test_seeded_six_tier_differential(seed):
     """interp == v1 == v2 == jaxc == pallas == pallas32 == native on
     >= 20 seeded boundary-biased programs (ret AND ctx writeback).  The
-    pallas32 leg runs unconditionally — it needs no x64; the uint64
-    in-graph legs are included whenever the build's x64 scope works, the
-    native leg whenever the host has a C toolchain (have_cc)."""
+    pallas32 leg needs no x64; the uint64 in-graph legs run under
+    ``jax.enable_x64``, the native leg whenever the host has a C
+    toolchain (have_cc)."""
+    import jax
+
     from repro.core.lower32 import (compile_jax32, ctx_to_vec32,
                                     ret32_to_int, vec32_to_bytes)
 
@@ -139,17 +141,15 @@ def test_seeded_six_tier_differential(seed):
         b = bytearray(buf0)
         results["native"] = (fn_n(b), bytes(b))
 
-    from repro.compat import enable_x64, have_x64
-    if have_x64():
-        from repro.core.jaxc import compile_jax, ctx_to_vec
-        from repro.core.pallasc import compile_pallas
-        for tier, fn in (("jaxc", compile_jax(prog)[0]),
-                         ("pallas", compile_pallas(prog, mode="jit",
-                                                   word_width=64)[0])):
-            with enable_x64(True):
-                ret, vec, _ = fn(ctx_to_vec(bytearray(buf0)), {})
-                results[tier] = (int(ret),
-                                 np.asarray(vec).astype("<u8").tobytes())
+    from repro.core.jaxc import compile_jax, ctx_to_vec
+    from repro.core.pallasc import compile_pallas
+    for tier, fn in (("jaxc", compile_jax(prog)[0]),
+                     ("pallas", compile_pallas(prog, mode="jit",
+                                               word_width=64)[0])):
+        with jax.enable_x64(True):
+            ret, vec, _ = fn(ctx_to_vec(bytearray(buf0)), {})
+            results[tier] = (int(ret),
+                             np.asarray(vec).astype("<u8").tobytes())
 
     want = results["interp"]
     for tier, got in results.items():
@@ -203,21 +203,17 @@ def _tier_builders():
     every tier available in this environment beyond the interpreter.
     In-graph tiers come wrapped in the real DeviceBridge (flush() after
     the run reconciles device-resident hash state back to host maps)."""
-    from repro.compat import have_x64
     from repro.core.cc import compile_native, have_cc
     from repro.core.pallasc import compile_host
     builders = {
         "v1": lambda p, m, v: compile_program(p, m, codegen="v1"),
         "v2": lambda p, m, v: compile_program(p, m, info=v),
         "pallas32": lambda p, m, v: compile_host(p, m, v, tier="pallas32"),
+        "jaxc": lambda p, m, v: compile_host(p, m, v, tier="jaxc"),
+        "pallas": lambda p, m, v: compile_host(p, m, v, tier="pallas"),
     }
     if have_cc():
         builders["native"] = compile_native
-    if have_x64():
-        builders["jaxc"] = lambda p, m, v: compile_host(p, m, v,
-                                                        tier="jaxc")
-        builders["pallas"] = lambda p, m, v: compile_host(p, m, v,
-                                                          tier="pallas")
     return builders
 
 
