@@ -58,6 +58,7 @@ from .maps import BpfMap, MapError, MapRegistry, RingView
 from .program import Program
 from .verifier import VerifierError, verify_with_info
 from .vm import VM
+from ..obs.spans import span
 
 _ZERO8 = bytes(8)
 
@@ -711,7 +712,7 @@ class PolicyRuntime:
             raise LinkError(
                 f"cannot replace {link.section!r} link with a "
                 f"{program.section!r} program")
-        with self._load_lock:
+        with span("repro.policy.replace"), self._load_lock:
             if not link._attached:
                 raise LinkError(f"{link!r} is detached; attach a new link")
             # verify-then-CAS: _prepare raises on rejection with the old

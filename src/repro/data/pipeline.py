@@ -20,6 +20,7 @@ from typing import Dict, Iterator, Optional
 import numpy as np
 
 from ..models.config import ModelConfig
+from ..obs import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,19 +81,35 @@ class SyntheticLMDataset:
 
 
 class _Prefetcher:
-    """Background-thread prefetch (host-side pipeline overlap)."""
+    """Background-thread prefetch (host-side pipeline overlap).
+
+    Each batch is a ``repro.data.batch`` span whose parent is the span
+    open where the prefetcher was made (the trainer's run).  Counters:
+    ``repro.data.batches_started``, ``batches_used`` (handed to the
+    consumer) and ``batches_dropped`` (started and never handed out, as
+    counted at :meth:`stop`: the batch in the making and any queued)."""
 
     def __init__(self, ds: SyntheticLMDataset, depth: int, start: int = 0):
         self.ds = ds
         self.q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._step = start
+        self._parent = spans.current()
+        self._lock = threading.Lock()       # orders a batch's start and stop
+        self._started = self._used = 0
         self._t = threading.Thread(target=self._work, daemon=True)
         self._t.start()
 
     def _work(self):
-        while not self._stop.is_set():
-            b = self.ds.batch(self._step)
+        while True:
+            with self._lock:
+                if self._stop.is_set():
+                    return
+                self._started += 1
+            spans.count("repro.data.batches_started")
+            with spans.span("repro.data.batch", parent=self._parent,
+                            step=self._step):
+                b = self.ds.batch(self._step)
             self._step += 1
             while not self._stop.is_set():
                 try:
@@ -103,10 +120,18 @@ class _Prefetcher:
 
     def __iter__(self):
         while True:
-            yield self.q.get()
+            b = self.q.get()
+            self._used += 1
+            spans.count("repro.data.batches_used")
+            yield b
 
     def stop(self):
-        self._stop.set()
+        with self._lock:
+            if self._stop.is_set():
+                return
+            self._stop.set()
+            spans.count("repro.data.batches_dropped",
+                        self._started - self._used)
 
 
 def make_dataset(cfg: ModelConfig, dcfg: DataConfig, *,

@@ -8,19 +8,24 @@ The ``("data", "model")`` mesh spans every device the process sees, with
 are FSDP-sharded over the data axis.  ``--smoke`` swaps in the reduced
 config of the same family (CPU-sized); ``--layers`` cuts the depth of
 the published config so it fits fewer chips, keeping its widths.
+
+The last line gives the final loss and where the steps' time went: the
+mean milliseconds per step of waiting for data, upload, dispatch,
+waiting for the device and the step's bookkeeping, and the prefetch
+thread's batches started, used and dropped (:func:`span_summary`).
 """
 
 from __future__ import annotations
 
 import argparse
-from typing import Optional, Sequence
-
-import numpy as np
+import collections
+from typing import Dict, List, Optional, Sequence
 
 from ..configs import get_config, get_smoke_config
 from ..core.runtime import PolicyRuntime
 from ..collectives.dispatch import reset_dispatcher
 from ..data import DataConfig
+from ..obs import spans
 from ..train import AdamWConfig, Trainer, TrainerConfig, TrainStepConfig
 from .cache import enable_compile_cache
 from .mesh import make_device_mesh, mesh_axes
@@ -77,6 +82,30 @@ def build_trainer(args: argparse.Namespace,
     return Trainer(cfg, ax, mesh, tcfg)
 
 
+def span_summary(log: List[Dict[str, float]]) -> str:
+    """Where the logged steps' time went, read from the span store: mean
+    milliseconds per step of each part of ``repro.train.step``, over the
+    steps after the first two (which compile), and the prefetch thread's
+    batches started, used and dropped."""
+    steps = {m["step"] for m in (log[2:] or log)}
+    held = spans.snapshot()["spans"]
+    ids = {s["id"] for s in held if s["name"] == "repro.train.step"
+           and s["attrs"]["step"] in steps}
+    ns: Dict[str, int] = collections.defaultdict(int)
+    for s in held:
+        if s["parent"] in ids and s["end_ns"] is not None:
+            ns[s["name"]] += s["end_ns"] - s["start_ns"]
+    parts = ", ".join(
+        f"{p.removeprefix('train.')} {ns['repro.' + p] * 1e-6 / len(ids):.1f}"
+        for p in ("data.wait", "train.upload", "train.dispatch",
+                  "train.device_wait", "train.post"))
+    c = spans.counters()
+    batches = ", ".join(f"{k} {c.get('repro.data.batches_' + k, 0)}"
+                        for k in ("started", "used", "dropped"))
+    return (f"ms per step over {len(ids)} steps: {parts}; "
+            f"batches {batches}")
+
+
 def main(argv: Optional[Sequence[str]] = None):
     args = parse_args(argv)
     enable_compile_cache()
@@ -85,7 +114,7 @@ def main(argv: Optional[Sequence[str]] = None):
         print(f"restored from step {tr.step_idx}")
     log = tr.run()
     print(f"final loss {log[-1]['loss']:.4f} over {len(log)} steps; "
-          f"mean step {np.mean([m['step_time_s'] for m in log[2:]]):.3f}s")
+          f"{span_summary(log)}")
 
 
 if __name__ == "__main__":

@@ -29,8 +29,8 @@ import dataclasses
 import struct
 from typing import Dict, List, Optional
 
+from ..core import runtime as _runtime    # a module: runtime imports obs
 from ..core.maps import MapError, RingView
-from ..core.runtime import PolicyRuntime, global_runtime
 
 EVENT_STRUCT = struct.Struct("<4Q")
 
@@ -68,10 +68,10 @@ class FlightRecorder:
     its counters into their structured health dict (satellite surface:
     one place to read bridge stats + observability loss accounting)."""
 
-    def __init__(self, runtime: Optional[PolicyRuntime] = None, *,
+    def __init__(self, runtime: Optional[_runtime.PolicyRuntime] = None, *,
                  capacity: int = 1024, events_map: str = "events",
                  hist_map: str = "lat_hist", register: bool = True):
-        self.runtime = runtime or global_runtime()
+        self.runtime = runtime or _runtime.global_runtime()
         self.events_map = events_map
         self.hist_map = hist_map
         self.capacity = capacity

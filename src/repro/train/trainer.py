@@ -24,6 +24,7 @@ from ..data import DataConfig, make_dataset
 from ..models import init_params
 from ..models.config import ModelConfig
 from ..models.layers import MeshAxes
+from ..obs.spans import span
 from .checkpoint import latest_step, load_checkpoint, save_checkpoint
 from .optimizer import adamw_init
 from .step import (TrainStepConfig, make_train_step, named_shardings,
@@ -54,6 +55,7 @@ class Trainer:
         self._build_step()
         self._policy_epoch = dispatcher().epoch
         self.step_idx = 0
+        self._runs = 0
 
     def _init_state(self):
         """Parameters and AdamW state, created already sharded over the
@@ -94,28 +96,57 @@ class Trainer:
 
     # -- main loop --------------------------------------------------------------
     def run(self, *, steps: Optional[int] = None) -> List[Dict[str, float]]:
+        """``steps`` steps (default ``tcfg.steps``) fed by a prefetch
+        thread of its own.
+
+        Spans (:mod:`repro.obs.spans`): ``repro.train.run`` per call, and
+        per step a ``repro.train.step`` whose children are, in order:
+
+        * ``repro.train.rebuild`` after a policy swap;
+        * ``repro.data.wait``, the next batch from the prefetch thread;
+        * ``repro.train.upload``;
+        * ``repro.train.dispatch``: trace and compile (or cache load) when
+          the step program is new, else the enqueue;
+        * ``repro.train.device_wait``;
+        * ``repro.train.post``: metrics, profiler feed, log, checkpoint.
+
+        A step's ``step_time_s`` is its dispatch plus its device wait."""
         steps = steps or self.tcfg.steps
-        data = make_dataset(self.cfg, self.tcfg.data,
-                            start_step=self.step_idx)
-        it = iter(data)
-        disp = dispatcher()
-        t_last = time.perf_counter()
-        try:
-            for _ in range(steps):
-                # live policy hot-reload: epoch bump -> rebuild (retrace)
-                if disp.epoch != self._policy_epoch:
+        self._runs += 1
+        with span("repro.train.run", run=self._runs):
+            data = make_dataset(self.cfg, self.tcfg.data,
+                                start_step=self.step_idx)
+            it = iter(data)
+            disp = dispatcher()
+            try:
+                for _ in range(steps):
+                    self._step(it, disp)
+            finally:
+                if hasattr(data, "stop"):
+                    data.stop()
+        return self.metrics_log
+
+    def _step(self, it, disp) -> None:
+        n = self.step_idx + 1
+        with span("repro.train.step", step_trace=True, step=n):
+            # live policy hot-reload: epoch bump -> rebuild (retrace)
+            if disp.epoch != self._policy_epoch:
+                with span("repro.train.rebuild"):
                     self._policy_epoch = disp.epoch
                     self._build_step()
-
-                batch = {k: jax.numpy.asarray(v)
-                         for k, v in next(it).items()}
-                t0 = time.perf_counter()
+            with span("repro.data.wait"):
+                raw = next(it)
+            with span("repro.train.upload"):
+                batch = {k: jax.numpy.asarray(v) for k, v in raw.items()}
+            with span("repro.train.dispatch") as call:
                 self.params, self.opt_state, metrics = self._step_fn(
                     self.params, self.opt_state, batch)
+            with span("repro.train.device_wait") as wait:
                 jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
-                self.step_idx += 1
+            dt = call.seconds + wait.seconds
+            self.step_idx += 1
 
+            with span("repro.train.post"):
                 # profiler plugin feed: step latency -> shared eBPF maps
                 disp.profiler_feed(
                     comm_id=0, latency_ns=int(dt * 1e9),
@@ -133,7 +164,3 @@ class Trainer:
                 if self.tcfg.ckpt_every and \
                         self.step_idx % self.tcfg.ckpt_every == 0:
                     self.save()
-        finally:
-            if hasattr(data, "stop"):
-                data.stop()
-        return self.metrics_log
