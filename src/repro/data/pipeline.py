@@ -33,6 +33,12 @@ class DataConfig:
     prefetch: int = 2
 
 
+def _cdf(p: np.ndarray) -> np.ndarray:
+    cdf = p.cumsum()
+    cdf /= cdf[-1]
+    return cdf
+
+
 class SyntheticLMDataset:
     """Markov-chain synthetic text: learnable structure, measurable loss."""
 
@@ -48,6 +54,9 @@ class SyntheticLMDataset:
         ranks = np.arange(1, V + 1, dtype=np.float64)
         zipf = 1.0 / ranks ** dcfg.zipf_a
         self.unigram = zipf / zipf.sum()
+        # the tables RandomState.choice would rebuild on every call
+        self.succ_cdf = _cdf(self.succ_p)
+        self.unigram_cdf = _cdf(self.unigram)
 
     def batch(self, step: int) -> Dict[str, np.ndarray]:
         d, c = self.dcfg, self.cfg
@@ -55,15 +64,27 @@ class SyntheticLMDataset:
         B, S = d.global_batch, d.seq_len
         toks = np.empty((B, S + 1), np.int32)
         toks[:, 0] = rng.randint(0, c.vocab, B)
-        # vectorized markov walk with 20% unigram resets
+        # markov walk with 20% unigram resets.  Per position it reads, in
+        # the order of rng.choice(4, B, p=succ_p), rng.rand(B) and
+        # rng.choice(V, n_resets, p=unigram): B successor uniforms, B reset
+        # coins, one uniform per reset.  All are drawn in one call (at most
+        # 3 a row), then the generator is left where those calls leave it.
+        state = rng.get_state()
+        u = rng.random_sample(3 * B * S)
+        at = 0
         for t in range(1, S + 1):
-            state = toks[:, t - 1] % self.n_states
-            choice = rng.choice(4, size=B, p=self.succ_p)
-            nxt = self.succ[state, choice]
-            reset = rng.rand(B) < 0.2
-            nxt[reset] = rng.choice(c.vocab, size=reset.sum(),
-                                    p=self.unigram)
+            choice = self.succ_cdf.searchsorted(u[at:at + B], side="right")
+            nxt = self.succ[toks[:, t - 1] % self.n_states, choice]
+            reset = u[at + B:at + 2 * B] < 0.2
+            at += 2 * B
+            k = int(reset.sum())
+            if k:
+                nxt[reset] = self.unigram_cdf.searchsorted(u[at:at + k],
+                                                           side="right")
+                at += k
             toks[:, t] = nxt
+        rng.set_state(state)
+        rng.random_sample(at)
         out = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
         if c.family == "audio":
             out["frames"] = rng.randn(B, c.n_audio_frames,
