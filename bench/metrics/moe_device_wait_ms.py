@@ -1,0 +1,4 @@
+"""moe_device_wait_ms: ``device_wait_ms`` read in the Moonlight training cell, where it
+moves that cell's ``tokens_per_s``."""
+
+from bench.metrics.device_wait_ms import read  # noqa: F401

@@ -21,6 +21,7 @@ ARCH_IDS = [
     "recurrentgemma-9b",
     "tinyllama-1.1b",
     "stablelm-12b",
+    "moonlight-16b-a3b",
 ]
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

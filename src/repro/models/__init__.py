@@ -5,8 +5,8 @@ with every collective routed through the policy dispatcher.
 """
 
 from .config import ModelConfig
-from .transformer import (decode_step, forward_logits, init_params,
-                          loss_fn, prefill)
+from .transformer import (decode_step, expert_choices, forward_logits,
+                          init_params, loss_and_stats, loss_fn, prefill)
 
-__all__ = ["ModelConfig", "decode_step", "forward_logits", "init_params",
-           "loss_fn", "prefill"]
+__all__ = ["ModelConfig", "decode_step", "expert_choices", "forward_logits",
+           "init_params", "loss_and_stats", "loss_fn", "prefill"]

@@ -28,9 +28,17 @@ class ModelConfig:
     attention: str = "full"                  # full | sliding | chunked
     window: int = 4096                       # sliding/chunked width
     nope_every: int = 0                      # llama4 iRoPE: every k-th layer no rope
+    # latent attention (MLA, DeepSeek-V2 §2.1), on when kv_lora_rank > 0:
+    # q per head [nope | rope], a latent kv of kv_lora_rank plus one rope
+    # key shared by the heads, values of v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # norm / mlp
     norm: str = "rmsnorm"                    # rmsnorm | layernorm
+    norm_eps: float = 1e-6                   # RMSNorm's epsilon
     mlp: str = "swiglu"                      # swiglu | gelu
     tie_embeddings: bool = False
 
@@ -39,8 +47,13 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0                        # expert hidden dim
     n_shared_experts: int = 0                # llama4 shared expert
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25            # 0: DeepSeek-V3 dropless
     router_aux_coef: float = 0.01
+    first_k_dense: int = 0                   # leading dense layers
+    experts_here: int = 0                    # held here, ids 0.. (0: all)
+    routed_scale: float = 1.0                # gates x this (deepseek_v3)
+    seq_aux_coef: float = 0.0                # sequence-wise balance loss alpha
+    bias_rate: float = 0.0                   # selection-bias update gamma
 
     # SSM / hybrid
     slstm_every: int = 0                     # xlstm: every k-th layer sLSTM
@@ -73,6 +86,19 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def dropless(self) -> bool:
+        return self.is_moe and self.capacity_factor == 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_here or self.n_experts
 
     @property
     def jdtype(self):
@@ -111,7 +137,14 @@ class ModelConfig:
                                  self.n_kv_heads, self.hd, self.d_ff,
                                  self.vocab, self.n_layers)
         emb = V * D * (1 if self.tie_embeddings else 2)
-        per_attn = D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
+        if self.is_mla:
+            r = self.kv_lora_rank
+            per_attn = (D * H * (self.qk_nope_dim + self.qk_rope_dim)
+                        + D * (r + self.qk_rope_dim) + r
+                        + r * H * (self.qk_nope_dim + self.v_head_dim)
+                        + H * self.v_head_dim * D)
+        else:
+            per_attn = D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
         if self.mlp == "swiglu":
             per_mlp = 3 * D * F
         else:
@@ -121,10 +154,12 @@ class ModelConfig:
         for i, k in enumerate(kinds):
             if k == "attn":
                 total += per_attn
-                if self.is_moe:
-                    e = (self.top_k if active_only else self.n_experts)
+                if self.is_moe and i >= self.first_k_dense:
+                    e = (self.top_k if active_only else self.n_held)
                     total += 3 * D * self.moe_d_ff * e
                     total += D * self.n_experts  # router
+                    if self.dropless:
+                        total += self.n_experts  # selection bias
                     if self.n_shared_experts:
                         total += 3 * D * self.moe_d_ff * self.n_shared_experts
                 elif F:

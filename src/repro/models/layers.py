@@ -123,10 +123,11 @@ def layer_norm(x, scale, bias, eps: float = 1e-5):
     return y.astype(dt) * scale.astype(dt) + bias.astype(dt)
 
 
-def apply_norm(kind: str, x, p):
-    if kind == "layernorm":
+def apply_norm(cfg, x, p):
+    """The configuration's norm (``cfg.norm``) with its parameters ``p``."""
+    if cfg.norm == "layernorm":
         return layer_norm(x, p["scale"], p["bias"])
-    return rms_norm(x, p["scale"])
+    return rms_norm(x, p["scale"], cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,11 @@ Layout:
 Capacity C = ceil(T·k / E · capacity_factor); overflow tokens are dropped
 (standard top-k capacity routing).  Aux losses: load-balance (Switch) +
 router z-loss.
+
+A configuration with ``capacity_factor`` 0 takes :func:`dropless_block`
+instead: DeepSeek-V3's routing (sigmoid scores, a selection-only bias,
+routed scaling, the sequence-wise balance loss) over all ``n_experts``,
+computed for the ``experts_here`` held on this chip, with no pair dropped.
 """
 
 from __future__ import annotations
@@ -28,7 +33,9 @@ from jax import lax
 from ..collectives.dispatch import dispatcher
 from ..core.context import AxisKind
 from .config import ModelConfig
-from .layers import MeshAxes, fsdp_gather
+from .layers import MeshAxes, col_linear, fsdp_gather, row_linear
+
+F32 = jnp.float32
 
 
 def router_topk(logits, k: int):
@@ -138,10 +145,136 @@ def moe_block(p, x, cfg: ModelConfig, ax: MeshAxes
 
     # --- shared experts (llama4): dense TP path over the FULL token set --------
     if cfg.n_shared_experts:
-        from .layers import col_linear, row_linear
-        hs = col_linear(xt_full, p["shared_w1"], ax, fsdp_dim=0)
-        us = col_linear(xt_full, p["shared_w3"], ax, fsdp_dim=0)
-        hs = jax.nn.silu(hs.astype(jnp.float32)).astype(xt_full.dtype) * us
-        y = y + row_linear(hs, p["shared_w2"], ax, fsdp_dim=1)
+        y = y + _shared(p, xt_full, ax)
 
     return y.reshape(B, S, D), aux
+
+
+def _shared(p, xt, ax: MeshAxes):
+    """The shared experts, one SwiGLU over every token."""
+    hs = col_linear(xt, p["shared_w1"], ax, fsdp_dim=0)
+    us = col_linear(xt, p["shared_w3"], ax, fsdp_dim=0)
+    hs = jax.nn.silu(hs.astype(F32)).astype(xt.dtype) * us
+    return row_linear(hs, p["shared_w2"], ax, fsdp_dim=1)
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``.  Its
+    transpose is the gather by ``inv``, where AD would scatter-add."""
+    return x[idx]
+
+
+def _permute_fwd(x, idx, inv):
+    return x[idx], (idx, inv)
+
+
+def _permute_bwd(res, g):
+    return g[res[1]], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _grouped(x, w, sizes, rows):
+    """``lax.ragged_dot`` over the groups ``sizes``, with the rows past the
+    groups' end (``rows`` False) zero, in the value and in every gradient:
+    the TPU kernel leaves them unwritten in both directions."""
+    return jnp.where(rows, lax.ragged_dot(x, w, sizes), 0)
+
+
+def route_scores(p, xt, cfg: ModelConfig):
+    """DeepSeek-V3 routing of tokens ``xt`` (T, D) over all ``n_experts``:
+    float32 sigmoid scores ``s``, the top-k chosen on ``s + e_bias`` (the
+    bias selects and never weights), and the gates ``s_chosen /
+    sum(s_chosen) * routed_scale``.  Returns (s, idx, gates)."""
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", xt.astype(F32), p["router"],
+                                  precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(p["e_bias"]), cfg.top_k)
+    sel = jnp.take_along_axis(s, idx, axis=-1)
+    gates = sel / jnp.sum(sel, -1, keepdims=True) * cfg.routed_scale
+    return s, idx, gates
+
+
+def dropless_block(p, x, cfg: ModelConfig, ax: MeshAxes):
+    """x: (B, S, D) -> (out, aux_loss, stats).
+
+    Routes every token over all ``n_experts`` and computes the part of the
+    layer that the experts held here (ids ``0 .. n_held - 1``) give: the
+    (token, choice) pairs routed to them are sorted by expert into a
+    buffer of the worst case, T x top_k rows, and the three expert
+    matmuls are one ragged grouped product over the held groups.  No
+    pair is dropped however uneven the routing.  The absent experts' part
+    is left out; the shared experts run over every token.
+
+    aux is the sequence-wise balance loss (DeepSeek-V3 §2.1.2), over all
+    experts: ``alpha * sum_i f_i P_i`` per sequence, ``f_i`` the share of
+    the sequence's choices that went to expert i times E / k, ``P_i`` the
+    mean of i's score normalised over the experts; the mean over the
+    batch.  stats: ``load`` (E,) choices per expert, which the step feeds
+    to :func:`update_expert_bias`, the counters ``pairs_here`` and
+    ``max_expert_pairs`` (the largest held group), and ``choices`` (T, k),
+    the experts each token chose."""
+    B, S, D = x.shape
+    E, k, Eh = cfg.n_experts, cfg.top_k, cfg.n_held
+    T = B * S
+    xt = x.reshape(T, D)
+    with jax.named_scope("repro.moe.route"):
+        s, idx, gates = route_scores(p, xt, cfg)
+        chosen = jnp.sum(jax.nn.one_hot(idx, E, dtype=F32), axis=1)  # (T, E)
+        f = jnp.sum(chosen.reshape(B, S, E), axis=1) * (E / (k * S))
+        P = jnp.mean((s / jnp.sum(s, -1, keepdims=True)).reshape(B, S, E),
+                     axis=1)
+        aux = cfg.seq_aux_coef * jnp.mean(jnp.sum(f * P, axis=-1))
+
+        e = idx.reshape(-1)                                       # (T*k,)
+        group = jnp.where(e < Eh, e, Eh)          # Eh: not held, sorts last
+        order = jnp.argsort(group, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype), unique_indices=True)
+        sizes = jnp.sum(jax.nn.one_hot(group, Eh, dtype=jnp.int32), axis=0)
+        held = (group[order] < Eh)[:, None]       # rows the groups cover
+        xs = _permute(jnp.repeat(xt, k, axis=0), order, inv)     # (T*k, D)
+        xs = jnp.where(held, xs, 0)
+    with jax.named_scope("repro.moe.experts"):
+        w1 = fsdp_gather(p["w1"], ax, 1).astype(x.dtype)          # (Eh, D, Fe)
+        w3 = fsdp_gather(p["w3"], ax, 1).astype(x.dtype)
+        w2 = fsdp_gather(p["w2"], ax, 2).astype(x.dtype)          # (Eh, Fe, D)
+        h = _grouped(xs, w1, sizes, held)
+        u = _grouped(xs, w3, sizes, held)
+        a = jax.nn.silu(h.astype(F32)).astype(x.dtype) * u
+        o = _grouped(a, w2, sizes, held)
+    with jax.named_scope("repro.moe.route"):
+        w = gates.reshape(-1)[order][:, None].astype(o.dtype)
+        ow = _permute(o * w, inv, order)                  # pair order again
+        y = jnp.sum(ow.reshape(T, k, D).astype(F32), axis=1).astype(x.dtype)
+    if cfg.n_shared_experts:
+        with jax.named_scope("repro.moe.shared"):
+            y = y + _shared(p, xt, ax)
+    here = jnp.sum(e < Eh).astype(jnp.int32)
+    stats = {"load": jnp.sum(chosen, axis=0),
+             "pairs_here": here,
+             "max_expert_pairs": jnp.max(sizes),
+             "choices": idx}
+    return y.reshape(B, S, D), aux, stats
+
+
+def update_expert_bias(new_params, old_params, loads, cfg: ModelConfig):
+    """DeepSeek-V3's bias update, after the optimizer: each MoE layer's
+    ``e_bias`` becomes its value before the step plus ``bias_rate *
+    sign(mean load - load_i)``, over the loads of all experts as routed.
+    ``loads`` mirrors the params' ``blocks`` and ``tail`` lists (None for
+    a block with no MoE); the optimizer never moves the bias."""
+    out = dict(new_params)
+    for part in ("blocks", "tail"):
+        blocks = list(new_params[part])
+        for j, load in enumerate(loads[part]):
+            if load is None:
+                continue
+            old = old_params[part][j]["moe"]["e_bias"]
+            step = cfg.bias_rate * jnp.sign(
+                jnp.mean(load, axis=-1, keepdims=True) - load)
+            moe = {**blocks[j]["moe"], "e_bias": old + step}
+            blocks[j] = {**blocks[j], "moe": moe}
+        out[part] = blocks
+    return out

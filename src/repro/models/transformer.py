@@ -22,12 +22,13 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import attention as att
+from . import mla
 from . import recurrent as rec
 from .config import ModelConfig
 from .layers import (MeshAxes, apply_norm, vp_embed, vp_logits,
                      vp_logits_loss)
 from .mlp import mlp_block
-from .moe import moe_block
+from .moe import dropless_block, moe_block
 
 
 # ===========================================================================
@@ -103,16 +104,21 @@ def _mlp_params(key, cfg: ModelConfig, n: int, *, d_ff: Optional[int] = None):
 
 
 def _moe_params(key, cfg: ModelConfig, n: int):
+    """The router over all ``n_experts`` and the weights of the experts
+    held here (all of them unless ``experts_here`` says otherwise)."""
     ks = jax.random.split(key, 7)
-    E, Fe, D = cfg.n_experts, cfg.moe_d_ff, cfg.d_model
+    E, Fe, D, Eh = cfg.n_experts, cfg.moe_d_ff, cfg.d_model, cfg.n_held
     p = {"router": _dense(ks[0], (n, D, E), scale=0.02),
-         "w1": _dense(ks[1], (n, E, D, Fe)),
-         "w3": _dense(ks[2], (n, E, D, Fe)),
-         "w2": _dense(ks[3], (n, E, Fe, D))}
+         "w1": _dense(ks[1], (n, Eh, D, Fe)),
+         "w3": _dense(ks[2], (n, Eh, D, Fe)),
+         "w2": _dense(ks[3], (n, Eh, Fe, D))}
     s = {"router": P(None, None, None),
          "w1": P(None, "model", "data", None),
          "w3": P(None, "model", "data", None),
          "w2": P(None, "model", None, "data")}
+    if cfg.dropless:      # the selection bias: updated by the step, not AdamW
+        p["e_bias"] = jnp.zeros((n, E), jnp.float32)
+        s["e_bias"] = P(None, None)
     if cfg.n_shared_experts:
         Fs = Fe * cfg.n_shared_experts
         p["shared_w1"] = _dense(ks[4], (n, D, Fs))
@@ -179,14 +185,19 @@ def _rglru_params(key, cfg: ModelConfig, n: int):
 
 
 def _block_params(key, kind: str, cfg: ModelConfig, ax: MeshAxes, n: int,
-                  *, with_cross: bool = False):
+                  *, with_cross: bool = False, dense: bool = False):
+    """``dense``: one of the leading dense layers of a MoE stack."""
     ks = jax.random.split(key, 6)
     p, s = {}, {}
     p["ln1"], s["ln1"] = _norm_params(ks[0], cfg, n)
     if kind == "attn":
-        p["attn"], s["attn"] = _attn_params(ks[1], cfg, ax, n)
+        if cfg.is_mla:
+            p["attn"], s["attn"] = mla.init_params(
+                jax.random.split(ks[1], 4), cfg, n, _dense)
+        else:
+            p["attn"], s["attn"] = _attn_params(ks[1], cfg, ax, n)
         p["ln2"], s["ln2"] = _norm_params(ks[2], cfg, n)
-        if cfg.is_moe:
+        if cfg.is_moe and not dense:
             p["moe"], s["moe"] = _moe_params(ks[3], cfg, n)
         elif cfg.d_ff:
             p["mlp"], s["mlp"] = _mlp_params(ks[3], cfg, n)
@@ -209,7 +220,10 @@ def _block_params(key, kind: str, cfg: ModelConfig, ax: MeshAxes, n: int,
 
 
 def _period(cfg: ModelConfig) -> Tuple[Tuple[str, ...], int, int]:
+    """(kinds, period length, remainder) of the layers after the
+    ``first_k_dense`` leading ones."""
     kinds = cfg.block_kinds()
+    n = cfg.n_layers - cfg.first_k_dense
     if cfg.family == "ssm" and cfg.slstm_every:
         plen = cfg.slstm_every
     elif cfg.family == "hybrid" and cfg.rglru_pattern:
@@ -218,17 +232,33 @@ def _period(cfg: ModelConfig) -> Tuple[Tuple[str, ...], int, int]:
         plen = 1
     if cfg.nope_every:
         plen = plen * cfg.nope_every // math.gcd(plen, cfg.nope_every)
-    plen = min(plen, cfg.n_layers)
-    n_full = cfg.n_layers // plen
-    rem = cfg.n_layers - n_full * plen
+    plen = min(plen, n)
+    rem = n - n // plen * plen
     return kinds, plen, rem
+
+
+def _n_full(cfg: ModelConfig, plen: int) -> int:
+    return (cfg.n_layers - cfg.first_k_dense) // plen
+
+
+def _layer_params(params, cfg: ModelConfig, li: int):
+    """Layer ``li``'s parameters, unstacked."""
+    kinds, plen, rem = _period(cfg)
+    k, n_full = cfg.first_k_dense, _n_full(cfg, plen)
+    if li < k:
+        return jax.tree.map(lambda a: a[0], params["lead"][li])
+    if li < k + n_full * plen:
+        grp, pos_in = divmod(li - k, plen)
+        return jax.tree.map(lambda a: a[grp], params["blocks"][pos_in])
+    return jax.tree.map(lambda a: a[0],
+                        params["tail"][li - k - n_full * plen])
 
 
 def init_params(key, cfg: ModelConfig, ax: MeshAxes
                 ) -> Tuple[Dict, Dict]:
     """Returns (params, partition_specs) — global logical arrays."""
     kinds, plen, rem = _period(cfg)
-    n_full = cfg.n_layers // plen
+    n_full, k = _n_full(cfg, plen), cfg.first_k_dense
     keys = jax.random.split(key, plen + rem + 8)
 
     params: Dict[str, Any] = {}
@@ -246,16 +276,22 @@ def init_params(key, cfg: ModelConfig, ax: MeshAxes
         keys[-3], cfg, 1)
 
     with_cross = cfg.family == "audio"
+    if k:
+        params["lead"], specs["lead"] = [], []
+        for j, kj in enumerate(jax.random.split(keys[-6], k)):
+            p, s = _block_params(kj, kinds[j], cfg, ax, 1, dense=True)
+            params["lead"].append(p)
+            specs["lead"].append(s)
     params["blocks"], specs["blocks"] = [], []
     for j in range(plen):
-        p, s = _block_params(keys[j], kinds[j], cfg, ax, n_full,
+        p, s = _block_params(keys[j], kinds[k + j], cfg, ax, n_full,
                              with_cross=with_cross)
         params["blocks"].append(p)
         specs["blocks"].append(s)
     params["tail"], specs["tail"] = [], []
     for j in range(rem):
-        p, s = _block_params(keys[plen + j], kinds[n_full * plen + j], cfg,
-                             ax, 1, with_cross=with_cross)
+        p, s = _block_params(keys[plen + j], kinds[k + n_full * plen + j],
+                             cfg, ax, 1, with_cross=with_cross)
         params["tail"].append(p)
         specs["tail"].append(s)
 
@@ -292,20 +328,29 @@ def init_params(key, cfg: ModelConfig, ax: MeshAxes
 def _apply_block(p, kind: str, x, cfg: ModelConfig, ax: MeshAxes, *,
                  use_rope: bool = True, causal: bool = True,
                  enc_kv=None, aux_acc=None):
-    h = apply_norm(cfg.norm, x, p["ln1"])
+    """Returns (x, aux_acc, stats): stats are a dropless MoE layer's
+    (:func:`moe.dropless_block`), else None."""
+    h = apply_norm(cfg, x, p["ln1"])
+    stats = None
     if kind == "attn":
-        y = att.attention_train(p["attn"], h, cfg, ax, use_rope=use_rope,
-                                causal=causal)
+        if cfg.is_mla:
+            y = mla.attention_train(p["attn"], h, cfg, ax)
+        else:
+            y = att.attention_train(p["attn"], h, cfg, ax,
+                                    use_rope=use_rope, causal=causal)
         x = x + y
         if enc_kv is not None:
-            hx = apply_norm(cfg.norm, x, p["ln_x"])
+            hx = apply_norm(cfg, x, p["ln_x"])
             x = x + att.cross_attention(p["xattn"], hx, enc_kv, cfg, ax)
-        h2 = apply_norm(cfg.norm, x, p["ln2"])
-        if cfg.is_moe:
-            y2, aux = moe_block(p["moe"], h2, cfg, ax)
+        h2 = apply_norm(cfg, x, p["ln2"])
+        if "moe" in p:
+            if cfg.dropless:
+                y2, aux, stats = dropless_block(p["moe"], h2, cfg, ax)
+            else:
+                y2, aux = moe_block(p["moe"], h2, cfg, ax)
             if aux_acc is not None:
                 aux_acc += aux
-        elif cfg.d_ff:
+        elif "mlp" in p:
             y2 = mlp_block(p["mlp"], h2, cfg, ax)
         else:
             y2 = 0.0
@@ -316,10 +361,10 @@ def _apply_block(p, kind: str, x, cfg: ModelConfig, ax: MeshAxes, *,
         x = x + rec.slstm_block(p["slstm"], h, cfg, ax)
     elif kind == "rglru":
         x = x + rec.rglru_block(p["rglru"], h, cfg, ax)
-        h2 = apply_norm(cfg.norm, x, p["ln2"])
+        h2 = apply_norm(cfg, x, p["ln2"])
         if cfg.d_ff:
             x = x + mlp_block(p["mlp"], h2, cfg, ax)
-    return x, aux_acc
+    return x, aux_acc, stats
 
 
 def _use_rope(cfg: ModelConfig, layer_idx: int) -> bool:
@@ -334,20 +379,31 @@ def _use_rope(cfg: ModelConfig, layer_idx: int) -> bool:
 
 def _stack_forward(params, x, cfg: ModelConfig, ax: MeshAxes, *,
                    causal: bool = True, enc_kv=None):
-    """Scan the period-grouped stack.  Returns (x, aux_loss)."""
+    """The leading dense layers, then the scanned period-grouped stack,
+    then the remainder.  Returns (x, aux_loss, stats): stats per block of
+    ``params["blocks"]`` and ``params["tail"]`` (stacked over the scan),
+    None where a block has no dropless MoE."""
     kinds, plen, rem = _period(cfg)
-    n_full = cfg.n_layers // plen
+    k, n_full = cfg.first_k_dense, _n_full(cfg, plen)
     aux = jnp.zeros((), jnp.float32)
+    for j, p in enumerate(params.get("lead", [])):
+        pj = jax.tree.map(lambda a: a[0], p)
+        x, aux, _ = _apply_block(pj, kinds[j], x, cfg, ax,
+                                 use_rope=_use_rope(cfg, j), causal=causal,
+                                 enc_kv=enc_kv, aux_acc=aux)
+    stats = {"blocks": [None] * plen, "tail": []}
 
     if n_full > 0:
         def period_step(carry, xs):
             x, aux = carry
+            st = []
             for j in range(plen):
-                x, aux = _apply_block(xs[j], kinds[j], x, cfg, ax,
-                                      use_rope=_use_rope(cfg, j),
-                                      causal=causal, enc_kv=enc_kv,
-                                      aux_acc=aux)
-            return (x, aux), None
+                x, aux, sj = _apply_block(xs[j], kinds[k + j], x, cfg, ax,
+                                          use_rope=_use_rope(cfg, k + j),
+                                          causal=causal, enc_kv=enc_kv,
+                                          aux_acc=aux)
+                st.append(sj)
+            return (x, aux), st
 
         if cfg.remat:
             if cfg.remat_policy == "save_psum":
@@ -358,14 +414,16 @@ def _stack_forward(params, x, cfg: ModelConfig, ax: MeshAxes, *,
             else:
                 period_step = jax.checkpoint(period_step, prevent_cse=False)
         xs = tuple(params["blocks"])
-        (x, aux), _ = lax.scan(period_step, (x, aux), xs)
+        (x, aux), stats["blocks"] = lax.scan(period_step, (x, aux), xs)
     for j, p in enumerate(params["tail"]):
-        li = n_full * plen + j
+        li = k + n_full * plen + j
         pj = jax.tree.map(lambda a: a[0], p)
-        x, aux = _apply_block(pj, kinds[li], x, cfg, ax,
-                              use_rope=_use_rope(cfg, li),
-                              causal=causal, enc_kv=enc_kv, aux_acc=aux)
-    return x, aux
+        x, aux, sj = _apply_block(pj, kinds[li], x, cfg, ax,
+                                  use_rope=_use_rope(cfg, li),
+                                  causal=causal, enc_kv=enc_kv, aux_acc=aux)
+        stats["tail"].append(None if sj is None else
+                             jax.tree.map(lambda a: a[None], sj))
+    return x, aux, stats
 
 
 def _encode_audio(params, frames, cfg: ModelConfig, ax: MeshAxes):
@@ -375,13 +433,13 @@ def _encode_audio(params, frames, cfg: ModelConfig, ax: MeshAxes):
                                   qkv_bias=False, attention="full")
 
     def enc_step(x, p):
-        x, _ = _apply_block(p, "attn", x, enc_cfg, ax, use_rope=False,
-                            causal=False)
+        x, _, _ = _apply_block(p, "attn", x, enc_cfg, ax, use_rope=False,
+                               causal=False)
         return x, None
 
     x, _ = lax.scan(enc_step, x, params["enc_blocks"])
-    return apply_norm(cfg.norm, x, jax.tree.map(lambda a: a[0],
-                                                params["enc_norm"]))
+    return apply_norm(cfg, x, jax.tree.map(lambda a: a[0],
+                                           params["enc_norm"]))
 
 
 def embed_tokens(params, tokens, cfg: ModelConfig, ax: MeshAxes, dtype):
@@ -393,6 +451,11 @@ def embed_tokens(params, tokens, cfg: ModelConfig, ax: MeshAxes, dtype):
 def forward_hidden(params, batch, cfg: ModelConfig, ax: MeshAxes):
     """batch: dict with 'tokens' (B,S) [+ 'frames' | 'patches'].
     Returns (hidden (B,S',D), aux)."""
+    h, aux, _ = _forward_hidden(params, batch, cfg, ax)
+    return h, aux
+
+
+def _forward_hidden(params, batch, cfg: ModelConfig, ax: MeshAxes):
     dtype = cfg.jdtype
     tokens = batch["tokens"]
     x = embed_tokens(params, tokens, cfg, ax, dtype)
@@ -411,9 +474,10 @@ def forward_hidden(params, batch, cfg: ModelConfig, ax: MeshAxes):
         pat = batch["patches"].astype(dtype) @ proj
         x = jnp.concatenate([pat, x], axis=1)
 
-    x, aux = _stack_forward_dispatch(params, x, cfg, ax, enc_kv=enc_kv)
+    x, aux, stats = _stack_forward_dispatch(params, x, cfg, ax,
+                                            enc_kv=enc_kv)
     fn = jax.tree.map(lambda a: a[0], params["final_norm"])
-    return apply_norm(cfg.norm, x, fn), aux
+    return apply_norm(cfg, x, fn), aux, stats
 
 
 def _stack_forward_dispatch(params, x, cfg, ax, *, enc_kv=None):
@@ -426,13 +490,13 @@ def _stack_forward_dispatch(params, x, cfg, ax, *, enc_kv=None):
         def dec_step(carry, p):
             x, aux = carry
             kv = att.encode_kv(p["xattn"], enc_out, cfg, ax)
-            x, aux = _apply_block(p, "attn", x, cfg, ax, enc_kv=kv,
-                                  use_rope=False, aux_acc=aux)
+            x, aux, _ = _apply_block(p, "attn", x, cfg, ax, enc_kv=kv,
+                                     use_rope=False, aux_acc=aux)
             return (x, aux), None
 
         aux = jnp.zeros((), jnp.float32)
         (x, aux), _ = lax.scan(dec_step, (x, aux), params["blocks"][0])
-        return x, aux
+        return x, aux, {"blocks": [None], "tail": []}
     return _stack_forward(params, x, cfg, ax, enc_kv=None)
 
 
@@ -444,14 +508,47 @@ def forward_logits(params, batch, cfg: ModelConfig, ax: MeshAxes):
 
 def loss_fn(params, batch, cfg: ModelConfig, ax: MeshAxes):
     """Mean next-token CE (+ MoE aux).  batch['labels'] aligned to tokens."""
-    h, aux = forward_hidden(params, batch, cfg, ax)
+    return loss_and_stats(params, batch, cfg, ax)[0]
+
+
+def loss_and_stats(params, batch, cfg: ModelConfig, ax: MeshAxes):
+    """(loss, stats): :func:`loss_fn` and, for a dropless MoE stack, the
+    per-layer expert loads (``loads``, mirroring ``blocks`` and ``tail``)
+    and the step's counters: ``pairs_here`` summed over layers and
+    ``max_expert_pairs``, the largest held group of any layer.  Other
+    stacks give empty stats."""
+    h, aux, st = _forward_hidden(params, batch, cfg, ax)
     labels = batch["labels"]
     if h.shape[1] != labels.shape[1]:      # vlm: drop patch positions
         h = h[:, -labels.shape[1]:]
     head = params.get("lm_head", params["embed"])
     vpad = cfg.padded_vocab(ax.tp)
     ce = vp_logits_loss(h, head, labels, ax, cfg.vocab, vpad)
-    return ce + aux
+    layers = [x for x in st["blocks"] + st["tail"] if x is not None]
+    if not layers:
+        return ce + aux, {}
+    stats = {"loads": {part: [None if x is None else x["load"]
+                              for x in st[part]]
+                       for part in ("blocks", "tail")},
+             "pairs_here": sum(jnp.sum(x["pairs_here"]) for x in layers),
+             "max_expert_pairs": jnp.max(jnp.stack(
+                 [jnp.max(x["max_expert_pairs"]) for x in layers]))}
+    return ce + aux, stats
+
+
+def expert_choices(params, batch, cfg: ModelConfig, ax: MeshAxes):
+    """The experts every token chose in each dropless MoE layer, in layer
+    order: (MoE layers, tokens, top_k) ids over all ``n_experts``, from
+    the forward pass of :func:`loss_and_stats`."""
+    _, _, st = _forward_hidden(params, batch, cfg, ax)
+    _, plen, _ = _period(cfg)
+    k, n_full = cfg.first_k_dense, _n_full(cfg, plen)
+    layers = [(k + i * plen + j, s["choices"][i])
+              for j, s in enumerate(st["blocks"]) if s is not None
+              for i in range(n_full)]
+    layers += [(k + n_full * plen + j, s["choices"][0])
+               for j, s in enumerate(st["tail"]) if s is not None]
+    return jnp.stack([c for _, c in sorted(layers, key=lambda t: t[0])])
 
 
 # ===========================================================================
@@ -462,7 +559,9 @@ def init_caches(params, cfg: ModelConfig, B: int, ctx: int, ax: MeshAxes):
     kinds = cfg.block_kinds()
     caches = []
     for k in kinds:
-        if k == "attn":
+        if k == "attn" and cfg.is_mla:
+            caches.append(mla.init_cache(cfg, B, ctx, cfg.jdtype))
+        elif k == "attn":
             caches.append(att.init_cache(cfg, B, ctx, ax, cfg.jdtype))
         elif k == "mlstm":
             caches.append(rec.mlstm_init_state(cfg, B, ax))
@@ -479,33 +578,32 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig, ax: MeshAxes,
     Returns (next_token (B,1), new_caches).  Layers unrolled (decode HLO is
     small: S=1)."""
     dtype = cfg.jdtype
-    kinds, plen, rem = _period(cfg)
-    n_full = cfg.n_layers // plen
+    kinds = cfg.block_kinds()
     x = embed_tokens(params, token, cfg, ax, dtype)
 
     new_caches = []
     for li in range(cfg.n_layers):
         kind = kinds[li]
-        if li < n_full * plen:
-            grp, pos_in = divmod(li, plen)
-            p = jax.tree.map(lambda a: a[grp], params["blocks"][pos_in])
-        else:
-            p = jax.tree.map(lambda a: a[0],
-                             params["tail"][li - n_full * plen])
+        p = _layer_params(params, cfg, li)
         c = caches[li]
-        h = apply_norm(cfg.norm, x, p["ln1"])
+        h = apply_norm(cfg, x, p["ln1"])
         if kind == "attn":
-            y, c = att.attention_decode(p["attn"], h, c, cfg, ax, pos,
-                                        use_rope=_use_rope(cfg, li))
+            if cfg.is_mla:
+                y, c = mla.attention_decode(p["attn"], h, c, cfg, ax, pos)
+            else:
+                y, c = att.attention_decode(p["attn"], h, c, cfg, ax, pos,
+                                            use_rope=_use_rope(cfg, li))
             x = x + y
             if cfg.family == "audio" and enc_out is not None:
-                hx = apply_norm(cfg.norm, x, p["ln_x"])
+                hx = apply_norm(cfg, x, p["ln_x"])
                 kv = att.encode_kv(p["xattn"], enc_out, cfg, ax)
                 x = x + att.cross_attention(p["xattn"], hx, kv, cfg, ax)
-            h2 = apply_norm(cfg.norm, x, p["ln2"])
-            if cfg.is_moe:
+            h2 = apply_norm(cfg, x, p["ln2"])
+            if "moe" in p and cfg.dropless:
+                y2 = dropless_block(p["moe"], h2, cfg, ax)[0]
+            elif "moe" in p:
                 y2, _ = moe_block(p["moe"], h2, cfg, ax)
-            elif cfg.d_ff:
+            elif "mlp" in p:
                 y2 = mlp_block(p["mlp"], h2, cfg, ax)
             else:
                 y2 = 0.0
@@ -521,13 +619,13 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig, ax: MeshAxes,
             y, c = rec.rglru_block(p["rglru"], h, cfg, ax, state=c,
                                    return_state=True)
             x = x + y
-            h2 = apply_norm(cfg.norm, x, p["ln2"])
+            h2 = apply_norm(cfg, x, p["ln2"])
             if cfg.d_ff:
                 x = x + mlp_block(p["mlp"], h2, cfg, ax)
         new_caches.append(c)
 
     fn = jax.tree.map(lambda a: a[0], params["final_norm"])
-    x = apply_norm(cfg.norm, x, fn)
+    x = apply_norm(cfg, x, fn)
     head = params.get("lm_head", params["embed"])
     logits = vp_logits(x, head, ax, cfg.vocab)
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]

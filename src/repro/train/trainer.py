@@ -24,11 +24,11 @@ from ..data import DataConfig, make_dataset
 from ..models import init_params
 from ..models.config import ModelConfig
 from ..models.layers import MeshAxes
-from ..obs.spans import span
+from ..obs.spans import count, span
 from .checkpoint import latest_step, load_checkpoint, save_checkpoint
 from .optimizer import adamw_init
-from .step import (TrainStepConfig, make_train_step, named_shardings,
-                   opt_state_specs)
+from .step import (MOE_COUNTERS, TrainStepConfig, make_train_step,
+                   named_shardings, opt_state_specs)
 
 
 @dataclasses.dataclass
@@ -108,7 +108,10 @@ class Trainer:
         * ``repro.train.dispatch``: trace and compile (or cache load) when
           the step program is new, else the enqueue;
         * ``repro.train.device_wait``;
-        * ``repro.train.post``: metrics, profiler feed, log, checkpoint.
+        * ``repro.train.post``: metrics, profiler feed, log, checkpoint;
+          a dropless MoE stack's step counters go to the counters
+          ``repro.moe.pairs_here`` and ``.max_expert_pairs`` (the step's
+          largest held group, summed over steps).
 
         A step's ``step_time_s`` is its dispatch plus its device wait."""
         steps = steps or self.tcfg.steps
@@ -154,6 +157,9 @@ class Trainer:
                     ts_ns=time.monotonic_ns())
 
                 m = {k: float(np.asarray(v)) for k, v in metrics.items()}
+                for k in MOE_COUNTERS:
+                    if k in m:
+                        count("repro.moe." + k, int(m[k]))
                 m["step"] = self.step_idx
                 m["step_time_s"] = dt
                 self.metrics_log.append(m)
