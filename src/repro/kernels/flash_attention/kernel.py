@@ -10,6 +10,12 @@ the BlockSpec budget is bq·d + 2·(bq·bk) + 2·bk·d floats ≤ ~2 MiB VMEM
 for the default 128/512 tiles).
 
 Grid: (B·H, S/bq, T/bk) — kv innermost so scratch carries across it.
+
+No model calls this kernel: it has no backward pass, computes every tile
+whether masked or not, and casts its operands to float32.  Latent
+attention trains with JAX's splash-attention kernel (``models/mla.py``)
+and GQA attention with the plain softmax (``models/attention.py``);
+``tests/test_kernels.py`` checks this kernel against ``ref.py``.
 """
 
 from __future__ import annotations

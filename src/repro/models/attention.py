@@ -9,9 +9,8 @@ Tensor layout (inside shard_map):
 When tp > n_kv, each device keeps ALL kv heads (the standard KV-replication
 scheme for GQA under wide TP) and uses the group its local q heads map to.
 
-The flash-attention Pallas kernel (src/repro/kernels/flash_attention.py)
-is used on TPU for the training path; the pure-jnp path here is its oracle
-and the CPU/dry-run fallback.
+Every backend computes the softmax here in plain jnp; the flash-attention
+Pallas kernel (src/repro/kernels/flash_attention) is not called.
 """
 
 from __future__ import annotations

@@ -15,24 +15,44 @@ Rope rotates the halves of the ``dr`` columns (``layers.apply_rope``);
 DeepSeek's checkpoints interleave them, which for seeded weights is a
 fixed permutation of the rope columns of ``W_q`` and ``W_kv_a``.
 
-Training computes the causal softmax in blocks of query rows, each under
-``jax.checkpoint``, so that the S x S square of logits is never held
-whole.  The heads are not split over the model axis (every rank computes
-all of them); FSDP gathers the weights along their input dimension.
+Training computes the causal softmax in one of two ways, chosen by what
+the code can observe.  On the TPU, when ``FLASH_BLOCK`` divides S,
+JAX's splash-attention kernel (:func:`causal_flash`) computes it in
+tiles of ``FLASH_BLOCK`` x ``FLASH_BLOCK``: it skips the tiles above the
+diagonal, keeps the logits and the online softmax's statistics in the
+core's fast memory, and has its own dq and dkv kernels.  Elsewhere
+:func:`blockwise_attention` computes it in blocks of query rows against
+every key, each block under ``jax.checkpoint`` so that the S x S square
+of logits is never held whole; it is the kernel's oracle in the tests.
+Each training call counts, at trace time, the tiles of one head's square
+(``repro.mla.kv_tiles_total``) and those the kernel computes
+(``repro.mla.kv_tiles_computed``, none on the blockwise path).  Decode
+attends over the latent cache with the plain masked softmax.  The heads
+are not split over the model axis (every rank computes all of them);
+FSDP gathers the weights along their input dimension.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
 
+from ..obs import spans
 from .config import ModelConfig
 from .layers import MeshAxes, apply_rope, fsdp_gather, rms_norm, rope_freqs
 
 NEG_INF = -1e30
-Q_BLOCK = 512           # query rows per block of the training softmax
+Q_BLOCK = 512           # query rows per block of the blockwise softmax
+# query and key rows per tile of the TPU kernel: on a TPU v5e, MLA's
+# forward and backward at S=8192, H=16 took 15.8 ms in tiles of 1024,
+# 17.4 in tiles of 512 and 27.0 in tiles of 256
+FLASH_BLOCK = 1024
 
 
 def init_params(keys, cfg: ModelConfig, n: int, dense):
@@ -123,12 +143,57 @@ def blockwise_attention(q, k, v, *, block: int = Q_BLOCK):
     return jnp.moveaxis(o, 0, 1).reshape(B, S, H, v.shape[-1])
 
 
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(S: int, H: int, block: int, interpret: bool):
+    """The causal splash kernel over H heads of S rows, and the tiles of
+    one head's square it computes (its mask info's non-empty blocks).
+    Built out of any trace, so that the cached mask info holds arrays and
+    no tracers."""
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mha(
+            splash.MultiHeadMask([splash.CausalMask((S, S))] * H),
+            head_shards=1, q_seq_shards=1, interpret=interpret,
+            block_sizes=splash.BlockSizes(
+                block_q=block, block_kv=block, block_kv_compute=block,
+                block_q_dkv=block, block_kv_dkv=block,
+                block_kv_dkv_compute=block, block_q_dq=block,
+                block_kv_dq=block))
+    block_mask = np.asarray(kernel.fwd_mask_info.block_mask)
+    return kernel, int(np.count_nonzero(block_mask[0]))
+
+
+def causal_flash(q, k, v, *, block: int = FLASH_BLOCK):
+    """Causal attention by the splash kernel, compiled on the TPU and
+    interpreted elsewhere: (B,S,H,dq) x (B,S,H,dq) x (B,S,H,dv) ->
+    (B,S,H,dv); ``block`` divides S.  Splash applies no scale, so q is
+    scaled in float32 before it is cast back to its dtype."""
+    B, S, H, dq = q.shape
+    kernel, _ = _splash_kernel(S, H, block, jax.default_backend() != "tpu")
+    q = (q.astype(jnp.float32) * dq ** -0.5).astype(q.dtype)
+    heads = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+    return heads(jax.vmap(kernel)(heads(q), heads(k), heads(v)))
+
+
+def causal_attention(q, k, v):
+    """:func:`causal_flash` on the TPU when ``FLASH_BLOCK`` divides S, else
+    :func:`blockwise_attention`; counts the tiles of one head's square
+    and those the kernel computes."""
+    S, H = q.shape[1:3]
+    n = -(-S // FLASH_BLOCK)
+    spans.count("repro.mla.kv_tiles_total", n * n)
+    if jax.default_backend() == "tpu" and S % FLASH_BLOCK == 0:
+        spans.count("repro.mla.kv_tiles_computed",
+                    _splash_kernel(S, H, FLASH_BLOCK, False)[1])
+        return causal_flash(q, k, v)
+    return blockwise_attention(q, k, v)
+
+
 def attention_train(p, x, cfg: ModelConfig, ax: MeshAxes):
     with jax.named_scope("repro.mla"):
         S = x.shape[1]
         q, c, k_pe = _latent(p, x, cfg, ax, jnp.arange(S, dtype=jnp.int32))
         k, v = _keys_values(p, c, k_pe, cfg, ax)
-        return _out(p, blockwise_attention(q, k, v), ax)
+        return _out(p, causal_attention(q, k, v), ax)
 
 
 def init_cache(cfg: ModelConfig, B: int, ctx: int, dtype):
